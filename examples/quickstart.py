@@ -34,6 +34,7 @@ from repro.configs.base import ParallelismConfig
 from repro.data.pipeline import DSIPipeline
 from repro.data.storage import RemoteStorage
 from repro.data.synthetic import tiny
+from repro.launch.train import patchify_stub
 from repro.models.model import build
 from repro.train.optimizer import AdamW, warmup_cosine
 from repro.train.step import build_train_step
@@ -140,13 +141,8 @@ def run_seneca(args) -> None:
                            executor=args.executor)
         for _ in range(args.steps):
             raw = pipe.next_batch()
-            B = raw["images"].shape[0]
-            flat = raw["images"].reshape(B, -1)
-            T, D = cfg.frontend_tokens, cfg.d_model
-            reps = -(-T * D // flat.shape[1])
-            emb = np.tile(flat, (1, reps))[:, :T * D].reshape(B, T, D)
-            batch = {"patch_embeds": jax.numpy.asarray(emb,
-                                                       jax.numpy.bfloat16),
+            batch = {"patch_embeds": patchify_stub(
+                         raw["images"], cfg.frontend_tokens, cfg.d_model),
                      "labels": jax.numpy.asarray(
                          raw["labels"] % cfg.n_classes)}
             params, state, m = step(params, state, batch)

@@ -408,6 +408,14 @@ class SenecaService:
                              f"{REPARTITION_MODES}")
         if cfg.shards < 1:
             raise ValueError(f"shards must be >= 1, got {cfg.shards}")
+        if cfg.device_cache_bytes > 0 and cfg.shard_transport == "process":
+            # an accelerator belongs to one process: the HBM tier must
+            # live in the process that holds it, not in shard children
+            raise ValueError(
+                "device_cache_bytes > 0 needs the HBM tier in this "
+                "process; shard_transport='process' would build it in "
+                "spawned shard processes that cannot reach the device "
+                "this process holds (use shard_transport='sim')")
         # base profile with the *configured* cache size: the static solve,
         # and later every calibrated re-solve, all run against this
         self.hardware = cfg.hardware

@@ -41,7 +41,7 @@ class HeartbeatRegistry(LivenessRegistry):
 @dataclass
 class FTConfig:
     ckpt_dir: str = "/tmp/repro_ckpt"
-    ckpt_every: int = 50
+    ckpt_every: int = 50          # <= 0: no checkpoints at all
     keep: int = 3
     dead_after_s: float = 10.0
     batch_deadline_s: Optional[float] = None   # straggler cutoff
@@ -93,6 +93,12 @@ class ResilientTrainer:
                 self.cfg.ckpt_dir, {"params": self.params,
                                     "opt": self.opt_state})
         except (FileNotFoundError, ValueError, KeyError, OSError):
+            if any(getattr(x, "is_deleted", lambda: False)()
+                   for x in jax.tree.leaves(self._init_params)):
+                raise RuntimeError(
+                    "no usable checkpoint to restore and the initial "
+                    "state was donated to the step; checkpoint more "
+                    "often (FTConfig.ckpt_every > 0)") from None
             self.params = self._init_params
             self.opt_state = self._init_opt
             self.step = 0
@@ -120,7 +126,8 @@ class ResilientTrainer:
         self._restore()
 
     def run(self, n_steps: int) -> List[Dict]:
-        if ckpt.latest_step(self.cfg.ckpt_dir) is not None:
+        saving = self.cfg.ckpt_every > 0
+        if saving and ckpt.latest_step(self.cfg.ckpt_dir) is not None:
             self._restore()            # resume an interrupted run
         while self.step < n_steps:
             if self.failure_injector and self.failure_injector(self.step):
@@ -143,7 +150,8 @@ class ResilientTrainer:
             rec = {k: float(v) for k, v in metrics.items()}
             rec["step"] = self.step
             self.history.append(rec)
-            if self.step % self.cfg.ckpt_every == 0:
+            if saving and self.step % self.cfg.ckpt_every == 0:
                 self._checkpoint()
-        self._checkpoint()
+        if saving:
+            self._checkpoint()
         return self.history

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import pvary, shard_map
-
 
 def pipeline_forward(block_fn: Callable, params_stacked, x,
                      mesh: Mesh, axis: str = "pipe",
@@ -46,10 +44,11 @@ def pipeline_forward(block_fn: Callable, params_stacked, x,
         mb = B // microbatches
         bufs = x_local.reshape((microbatches, mb) + x_local.shape[1:])
         # carries become rank-varying inside the loop; mark them so
-        out = pvary(jnp.zeros_like(bufs), (axis,))
+        out = jax.lax.pcast(jnp.zeros_like(bufs), (axis,), to="varying")
         # steady-state loop: tick t processes microbatch (t - rank) at rank
-        cur = pvary(
-            jnp.zeros((mb,) + x_local.shape[1:], x_local.dtype), (axis,))
+        cur = jax.lax.pcast(
+            jnp.zeros((mb,) + x_local.shape[1:], x_local.dtype), (axis,),
+            to="varying")
         n_ticks = microbatches + n_stages - 1
         perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
 
@@ -78,7 +77,7 @@ def pipeline_forward(block_fn: Callable, params_stacked, x,
             jnp.where(rank == n_stages - 1, out, jnp.zeros_like(out)), axis)
         return out.reshape(x_local.shape)
 
-    f = shard_map(
+    f = jax.shard_map(
         stage, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P())

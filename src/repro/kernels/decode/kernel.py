@@ -1,12 +1,18 @@
 """Pallas decode + fused decode/augment kernels.
 
-One grid step synthesizes one image: the counter hash runs over a
-``broadcasted_iota`` index cube, so there is no source tile to stage — the
-"decode" reads nothing but two scalars per sample (base seed + header
-mix).  The fused variant hashes *only the crop window's* source indices
+One grid step synthesizes one row tile of one image: the counter hash
+runs over an index block built from ``broadcasted_iota``, so there is no
+source tile to stage — the "decode" reads nothing but two scalars per
+sample (base seed + header mix), delivered through scalar prefetch.
+The fused variant hashes *only the crop window's* source indices
 (mirrored columns under flip) and feeds the exact float pipeline of the
-augment kernel, emitting the normalized crop with no intermediate decoded
-image anywhere.
+augment kernel, emitting the normalized crop with no intermediate
+decoded image anywhere.
+
+Both kernels work on the lane-dense view ``(B, rows, width*3)``: the
+3-channel axis is folded into the minor (lane) dimension, where it
+would otherwise pad to 128 lanes on a TPU.  The ``(B, h, w, 3)``
+reshape happens outside the kernel.
 """
 from __future__ import annotations
 
@@ -16,21 +22,19 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.augment.kernel import MEAN, STD
+from repro.kernels.augment.kernel import normalize
 from repro.kernels.decode.ref import pixel_hash_jnp
-from repro.kernels.device import resolve_interpret
+from repro.kernels.device import resolve_interpret, row_block_iota, row_tile
 
 
-def _decode_kernel(base_ref, mix_ref, out_ref, *, h: int, w: int):
-    base = base_ref[0]
-    mix = mix_ref[0]
-    row = jax.lax.broadcasted_iota(jnp.uint32, (h, w, 3), 0)
-    col = jax.lax.broadcasted_iota(jnp.uint32, (h, w, 3), 1)
-    chan = jax.lax.broadcasted_iota(jnp.uint32, (h, w, 3), 2)
-    idx = (row * jnp.uint32(w) + col) * jnp.uint32(3) + chan
-    u8 = pixel_hash_jnp(base, idx).astype(jnp.int32)
-    out_ref[0] = ((u8 + mix) % 256).astype(jnp.uint8)
+def _decode_kernel(base_ref, mix_ref, out_ref):
+    b = pl.program_id(0)
+    row, lane = row_block_iota(out_ref)
+    idx = (row * out_ref.shape[2] + lane).astype(jnp.uint32)
+    u8 = pixel_hash_jnp(base_ref[b], idx).astype(jnp.int32)
+    out_ref[0] = ((u8 + mix_ref[b]) % 256).astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=("h", "w", "interpret"))
@@ -43,45 +47,36 @@ def decode(bases: jax.Array, mixes: jax.Array, *, h: int, w: int,
     """
     interpret = resolve_interpret(interpret)
     B = bases.shape[0]
-    kernel = functools.partial(_decode_kernel, h=h, w=w)
-    return pl.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b: (b,)),
-            pl.BlockSpec((1,), lambda b: (b,)),
-        ],
-        out_specs=pl.BlockSpec((1, h, w, 3), lambda b: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, h, w, 3), jnp.uint8),
+    rows = row_tile(h, 32)
+    out = pl.pallas_call(
+        _decode_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, h // rows), in_specs=[],
+            out_specs=pl.BlockSpec((1, rows, w * 3),
+                                   lambda b, t, *_: (b, t, 0))),
+        out_shape=jax.ShapeDtypeStruct((B, h, w * 3), jnp.uint8),
         interpret=interpret,
     )(bases.astype(jnp.uint32), mixes.astype(jnp.int32))
+    return out.reshape(B, h, w, 3)
 
 
 def _decode_augment_kernel(base_ref, mix_ref, top_ref, left_ref, flip_ref,
-                           out_ref, *, img_w: int, crop_h: int,
-                           crop_w: int):
-    base = base_ref[0]
-    mix = mix_ref[0]
-    top = top_ref[0]
-    left = left_ref[0]
-    flip = flip_ref[0]
-    i = jax.lax.broadcasted_iota(jnp.int32, (crop_h, crop_w, 3), 0)
-    j = jax.lax.broadcasted_iota(jnp.int32, (crop_h, crop_w, 3), 1)
-    c = jax.lax.broadcasted_iota(jnp.int32, (crop_h, crop_w, 3), 2)
-    # the flip is a source-index mirror: hash the pixel the flipped crop
-    # would have read, instead of materializing then reversing
-    src_j = jnp.where(flip != 0, crop_w - 1 - j, j)
-    row = (top + i).astype(jnp.uint32)
-    col = (left + src_j).astype(jnp.uint32)
-    idx = (row * jnp.uint32(img_w) + col) * jnp.uint32(3) \
-        + c.astype(jnp.uint32)
-    u8 = pixel_hash_jnp(base, idx).astype(jnp.int32)
-    pix = (u8 + mix) % 256
+                           out_ref, *, img_w: int, crop_w: int):
+    b = pl.program_id(0)
+    i, k = row_block_iota(out_ref)
+    c = k % 3
+    # the flip is a source-index mirror: output lane k = 3*j + c reads
+    # source column crop_w-1-j, i.e. lane (crop_w-1)*3 + 2*c - k of the
+    # crop window — hash the pixel the flipped crop would have read,
+    # instead of materializing then reversing
+    src = jnp.where(flip_ref[b] != 0, (crop_w - 1) * 3 + 2 * c - k, k)
+    idx = ((top_ref[b] + i) * (img_w * 3) + left_ref[b] * 3
+           + src).astype(jnp.uint32)
+    u8 = pixel_hash_jnp(base_ref[b], idx).astype(jnp.int32)
+    pix = (u8 + mix_ref[b]) % 256
     # from here: the augment kernel's exact float pipeline (/255, scalar
     # per-channel normalize) so fused == decode-then-augment bitwise
-    x = pix.astype(jnp.float32) / 255.0
-    chans = [(x[:, :, ch] - MEAN[ch]) / STD[ch] for ch in range(3)]
-    out_ref[0] = jnp.stack(chans, axis=-1).astype(out_ref.dtype)
+    out_ref[0] = normalize(pix.astype(jnp.float32), k).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("img_h", "img_w", "crop_h",
@@ -97,17 +92,18 @@ def decode_augment(bases: jax.Array, mixes: jax.Array, tops: jax.Array,
     interpret = resolve_interpret(interpret)
     del img_h  # part of the contract/signature; only img_w indexes memory
     B = bases.shape[0]
+    rows = row_tile(crop_h, 8)
     kernel = functools.partial(_decode_augment_kernel, img_w=img_w,
-                               crop_h=crop_h, crop_w=crop_w)
-    scalar = pl.BlockSpec((1,), lambda b: (b,))
-    return pl.pallas_call(
+                               crop_w=crop_w)
+    out = pl.pallas_call(
         kernel,
-        grid=(B,),
-        in_specs=[scalar] * 5,
-        out_specs=pl.BlockSpec((1, crop_h, crop_w, 3),
-                               lambda b: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, crop_h, crop_w, 3), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(B, crop_h // rows), in_specs=[],
+            out_specs=pl.BlockSpec((1, rows, crop_w * 3),
+                                   lambda b, t, *_: (b, t, 0))),
+        out_shape=jax.ShapeDtypeStruct((B, crop_h, crop_w * 3), out_dtype),
         interpret=interpret,
     )(bases.astype(jnp.uint32), mixes.astype(jnp.int32),
       tops.astype(jnp.int32), lefts.astype(jnp.int32),
       flips.astype(jnp.int32))
+    return out.reshape(B, crop_h, crop_w, 3)

@@ -21,8 +21,8 @@ from repro.configs import registry                           # noqa: E402
 from repro.configs.base import (SHAPES_BY_NAME, ALL_SHAPES,  # noqa: E402
                                 ParallelismConfig, ShapeConfig,
                                 shape_applicable)
-from repro.distributed.compat import set_mesh                # noqa: E402
 from repro.distributed.sharding import make_rules, use_rules  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_production_mesh           # noqa: E402
 from repro.models.model import Model, build                  # noqa: E402
 from repro.models.params import (abstract_params,            # noqa: E402
@@ -108,7 +108,7 @@ def lower_cell(arch: str, shape: ShapeConfig, *, multi_pod: bool,
     batch_abs = model.input_specs(shape)
 
     t0 = time.monotonic()
-    with use_rules(rules), set_mesh(mesh):
+    with use_rules(rules), jax.set_mesh(mesh):
         if shape.is_train:
             opt = AdamW(state_dtype=parallel.opt_state_dtype)
             o_abs = jax.eval_shape(opt.init, p_abs)
@@ -204,6 +204,7 @@ def lower_cell(arch: str, shape: ShapeConfig, *, multi_pod: bool,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all",
                     help="comma list or 'all' (assigned archs)")

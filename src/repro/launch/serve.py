@@ -1,6 +1,6 @@
 """Serving driver: batched decode with KV caches.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --reduced \
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b \
         --requests 8 --max-new 16
 
 ``--open-loop RATE`` feeds the resident model from the open-loop
@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build
 from repro.serve.step import Request, Server
 
@@ -70,10 +71,14 @@ def _open_loop_requests(args, vocab_size: int):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b",
                     choices=registry.list_archs())
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke-scale config (default); --no-reduced "
+                         "selects the full one")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=12)

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.distributed.compat import shard_map
 from repro.distributed.sharding import current_rules, shard
 from repro.models.params import ParamDef
 
@@ -181,7 +180,7 @@ def moe_ffn(p: Dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Arr
                 aux = jax.lax.pmean(aux, reduce_axes)
             return out.reshape(Bl, Sl, Dl), aux
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(batch_spec, None, None), P(None, None),
                       P(ep_axis, None, None), P(ep_axis, None, None),

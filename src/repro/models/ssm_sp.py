@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.distributed.compat import pvary, shard_map
 from repro.models.ssm import _ssd_core
 
 F32 = jnp.float32
@@ -76,8 +75,9 @@ def ssm_block_seq_parallel(p: Dict, x: jax.Array, cfg: ModelConfig,
         # ---- pass 1: local summaries (h0 = 0) ----
         chunk = min(s.chunk, S_loc)
         vary = tuple(batch_axes) + (axis,)
-        z0 = pvary(
-            jnp.zeros((B, nh, s.head_dim, s.d_state), F32), vary)
+        z0 = jax.lax.pcast(
+            jnp.zeros((B, nh, s.head_dim, s.d_state), F32), vary,
+            to="varying")
         _, S_r = _ssd_core(xh, dt, A, Bm, Cm, chunk, h0=z0)
         logD_r = jnp.sum(dt * A, axis=1)                  # (B, nh)
 
@@ -108,7 +108,7 @@ def ssm_block_seq_parallel(p: Dict, x: jax.Array, cfg: ModelConfig,
                p["A_log"], p["D_skip"], p["conv_x"], p["conv_B"],
                p["conv_C"], p["norm"], p["wo"])
     x_spec = P(batch_axes, axis, None)
-    f = shard_map(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec,) + (P(),) * len(weights),
         out_specs=x_spec)

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 from repro.models.model import Model
 from repro.train import compression
 from repro.train.optimizer import AdamW
@@ -47,8 +46,8 @@ def build_dp_train_step(model: Model, opt: AdamW, mesh: Mesh,
     def step(params, opt_state, ef, batch):
         in_specs = (P(), P(), P(), spec_for_batch(batch))
         out_specs = (P(), P(), P(), P())
-        f = shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+        f = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                              out_specs=out_specs)
         return f(params, opt_state, ef, batch)
 
     return step

@@ -129,3 +129,25 @@ def test_fused_decode_seed_gating():
     # the pipeline re-exports the same gate (lazy wrapper)
     assert pipeline_fds(base) == 42
     assert pipeline_fds(heavy) is None
+
+
+# ------------------------------------------------ interpret-mode probe
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    """Compiled Mosaic on TPU, the interpreter on CPU, and an error on
+    any other backend — never a silent interpreter run there."""
+    import jax
+
+    from repro.kernels import device
+    try:
+        for backend, want in [("tpu", False), ("cpu", True)]:
+            monkeypatch.setattr(jax, "default_backend", lambda: backend)
+            device.default_interpret.cache_clear()
+            assert device.default_interpret() is want
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        device.default_interpret.cache_clear()
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            device.resolve_interpret(None)
+        assert device.resolve_interpret(True) is True
+    finally:
+        monkeypatch.undo()
+        device.default_interpret.cache_clear()

@@ -157,7 +157,6 @@ def test_moe_ep_matches_local_dispatch():
         from jax.sharding import Mesh
         from repro.configs import registry
         from repro.configs.base import TRAIN_4K, ParallelismConfig
-        from repro.distributed.compat import set_mesh
         from repro.distributed.sharding import make_rules, use_rules
         from repro.models.model import build, make_batch
 
@@ -180,7 +179,7 @@ def test_moe_ep_matches_local_dispatch():
         shape = TRAIN_4K
         par = ParallelismConfig(ep=True)
         rules = make_rules(cfg, shape, par, tp_size=4, dp_size=2, mesh=mesh)
-        with use_rules(rules), set_mesh(mesh):
+        with use_rules(rules), jax.set_mesh(mesh):
             out, _ = jax.jit(lambda p, b: m.forward(p, b))(params, batch)
         d = float(jnp.max(jnp.abs(ref - out)))
         print('moe ep maxdiff', d)

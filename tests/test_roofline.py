@@ -25,7 +25,6 @@ def test_loop_aware_collective_bytes_exact():
     out = subprocess.run([sys.executable, "-c", textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from repro.distributed.compat import set_mesh
         from repro.roofline import hlo_collectives
         mesh = Mesh(np.asarray(jax.devices()[:4]), ('d',))
         def f(x, w):
@@ -36,7 +35,7 @@ def test_loop_aware_collective_bytes_exact():
             return out.sum()
         x = jax.ShapeDtypeStruct((16, 64), jnp.float32)
         w = jax.ShapeDtypeStruct((5, 64, 64), jnp.float32)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             c = jax.jit(jax.grad(f, argnums=1),
                         in_shardings=(NamedSharding(mesh, P('d', None)),
                                       NamedSharding(mesh, P())),

@@ -287,6 +287,21 @@ def test_process_transport_roundtrip_and_close():
     assert not os.path.exists(xchg)
 
 
+@pytest.mark.parametrize("shards", [1, 2])
+def test_process_transport_rejects_device_cache(shards):
+    """The HBM tier belongs in the process that holds the accelerator:
+    a device cache on process shards is refused at construction, before
+    any shard process is spawned."""
+    before = set(glob.glob(os.path.join(tempfile.gettempdir(),
+                                        "seneca-xchg-*")))
+    with pytest.raises(ValueError, match="shard_transport='process'"):
+        SenecaServer.for_dataset(tiny(n=32), device_cache_bytes=1 << 20,
+                                 shards=shards, shard_transport="process")
+    after = set(glob.glob(os.path.join(tempfile.gettempdir(),
+                                       "seneca-xchg-*")))
+    assert after == before
+
+
 def test_process_transport_failed_start_cleans_up():
     before = set(glob.glob(os.path.join(tempfile.gettempdir(),
                                         "seneca-xchg-*")))

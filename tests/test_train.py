@@ -105,3 +105,88 @@ def test_error_feedback_is_unbiased_over_steps():
         sent = sent + compression.decompress(q, s, g.shape)
     np.testing.assert_allclose(np.asarray(sent / 50), np.asarray(g),
                                atol=1e-4)
+
+
+# ----------------------------------------------------------------------
+# launch/train.py driver and the compile-cache helper
+def _train_argv(*extra):
+    return ["--arch", "vit-huge", "--steps", "2", "--batch", "4",
+            "--samples", "64", *extra]
+
+
+def test_reduced_is_a_real_switch():
+    from repro.launch import train
+    assert train.parse_args(["--arch", "vit-huge"]).reduced
+    full = train.model_config(train.parse_args(
+        ["--arch", "vit-huge", "--no-reduced"]))
+    assert (full.d_model, full.n_heads, full.d_ff, full.n_layers,
+            full.frontend_tokens, full.n_classes) == \
+        (1280, 16, 5120, 32, 197, 1000)
+    small = train.model_config(train.parse_args(["--arch", "vit-huge"]))
+    assert small.d_model < full.d_model
+
+
+def test_per_run_checkpoint_dir_takes_every_step(tmp_path, monkeypatch):
+    """Without --ckpt-dir each run checkpoints into a fresh directory of
+    its own (removed at the end), so a second identical run does not
+    resume the first and takes all its steps again."""
+    import tempfile
+
+    from repro.launch import train
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+    for _ in range(2):
+        out = train.run(train.parse_args(_train_argv()))
+        assert len(out["history"]) == 2
+    assert list(tmp_path.iterdir()) == []
+
+
+def test_finished_checkpoint_dir_is_a_clear_error(tmp_path):
+    """An explicit --ckpt-dir resumes; one that already holds the last
+    step leaves nothing to train, which is an error that says so."""
+    from repro.launch import train
+    argv = _train_argv("--ckpt-dir", str(tmp_path / "ckpt"))
+    assert len(train.run(train.parse_args(argv))["history"]) == 2
+    with pytest.raises(RuntimeError, match="no steps taken"):
+        train.run(train.parse_args(argv))
+
+
+def test_device_route_warm_epoch_moves_no_h2d_bytes():
+    """The device route through the driver: fused kernel for the cold
+    epoch, HBM tier for the warm one — no host->device payload bytes in
+    either, and every row of the warm epoch is an HBM hit."""
+    from repro.launch import train
+    out = train.run(train.parse_args([
+        "--arch", "vit-huge", "--steps", "16", "--batch", "8",
+        "--samples", "64", "--executor", "device", "--device-cache-mb",
+        "4", "--ckpt-every", "0"]))
+    assert out["h2d_by_epoch"] == [0, 0]
+    assert out["stats"]["hbm"]["augmented"]["hbm_hits"] >= 64
+    assert out["stats"]["refill_errors"] == 0
+    assert all(np.isfinite(h["loss"]) for h in out["history"])
+
+
+def test_compile_cache_leaves_env_setting_alone(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert calls == []
+
+
+def test_compile_cache_fixed_dir_in_checkout(monkeypatch):
+    """Unset env: the same in-checkout directory on every call, and git
+    ignores it.  (jax.config.update is stubbed: tests never turn the
+    persistent cache on.)"""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    first = compile_cache.enable_compile_cache()
+    second = compile_cache.enable_compile_cache()
+    root = Path(__file__).resolve().parents[1]
+    assert first == second == str(root / ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", first)] * 2
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
