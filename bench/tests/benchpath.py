@@ -1,0 +1,11 @@
+"""Puts the benchmark and the program on the path of a test module."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+DATA = os.path.join(BENCH, "tests", "data")
+for p in (os.path.join(ROOT, "src"), BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
