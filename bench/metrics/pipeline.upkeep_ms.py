@@ -1,0 +1,13 @@
+"""The device route's upkeep after each batch (the span ``upkeep``:
+``_process_refills`` and ``maybe_repartition``) per batch served in the
+window, in ms."""
+
+KEYS = ("upkeep",)
+
+
+def read(run):
+    before, after = run.times_before, run.times_after
+    n = after.get("batches", 0) - before.get("batches", 0)
+    if n <= 0 or not all(k in after for k in KEYS):
+        return None
+    return 1e3 * sum(after[k] - before[k] for k in KEYS) / n

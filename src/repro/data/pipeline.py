@@ -59,8 +59,8 @@ import threading
 import time
 import warnings
 from concurrent.futures import ThreadPoolExecutor
-from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from dataclasses import dataclass, field, fields
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -92,18 +92,72 @@ def fused_decode_seed(ds) -> Optional[int]:
     return impl(ds)
 
 
+class _Span:
+    """One :meth:`StageTimes.span`: the elapsed seconds of its body on
+    the pipeline's clock, added to the field ``name`` (and kept as
+    ``dt``), inside a profiler annotation ``seneca.<name>``."""
+    __slots__ = ("_times", "_name", "_note", "_t0", "dt")
+
+    def __init__(self, times: "StageTimes", name: str):
+        self._times, self._name = times, name
+        self.dt = 0.0
+
+    def __enter__(self) -> "_Span":
+        # imported here, so that importing this module never imports jax
+        from jax.profiler import TraceAnnotation
+        self._note = TraceAnnotation("seneca." + self._name)
+        self._note.__enter__()
+        self._t0 = self._times.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dt = self._times.now() - self._t0
+        setattr(self._times, self._name,
+                getattr(self._times, self._name) + self.dt)
+        self._note.__exit__(*exc)
+
+
 @dataclass
 class StageTimes:
+    """Seconds the pipeline spent in each stage, summed over its batches.
+
+    ``fetch``, ``decode``, ``augment`` and ``collate`` are the executors'
+    stage timers.  The device route also times its phases with
+    :meth:`span` (``next_batch`` and, within it and not overlapping,
+    ``sample``, ``gather``, ``fused``, ``augment``, ``rows``,
+    ``admit_rows``, ``collate`` and ``upkeep``) and, inside ``gather``,
+    sums per-sample counters: ``lookup`` (every tiered lookup) and
+    ``admit`` (encoded admissions).
+    ``patchify`` is the image feed's span (``launch/train.py``)."""
     fetch: float = 0.0
     decode: float = 0.0
     augment: float = 0.0
     collate: float = 0.0
     batches: int = 0
+    next_batch: float = 0.0
+    sample: float = 0.0
+    gather: float = 0.0
+    fused: float = 0.0
+    rows: float = 0.0
+    admit_rows: float = 0.0
+    upkeep: float = 0.0
+    patchify: float = 0.0
+    lookup: float = 0.0
+    admit: float = 0.0
+    now: Callable[[], float] = field(default=time.monotonic, repr=False,
+                                     compare=False)
+
+    def span(self, name: str) -> _Span:
+        """A context manager that adds its body's seconds to the field
+        ``name``, under a profiler annotation ``seneca.<name>`` that
+        records only while a profiler session runs."""
+        if not isinstance(getattr(self, name), float):
+            raise ValueError(f"StageTimes has no span field {name!r}")
+        return _Span(self, name)
 
     def as_dict(self) -> Dict[str, float]:
-        return {"fetch": self.fetch, "decode": self.decode,
-                "augment": self.augment, "collate": self.collate,
-                "batches": self.batches}
+        return {f.name: getattr(self, f.name) for f in fields(self)
+                if f.name != "now"}
 
 
 def plan_stage_workers(telemetry, n_workers: int) -> Tuple[int, int]:
@@ -639,7 +693,6 @@ class DSIPipeline:
                     f"kernel's semantics); got {type(self.ds).__name__}")
         self.bs = self.session.batch_size
         self.pool = ThreadPoolExecutor(max_workers=n_workers)
-        self.times = StageTimes()
         # pluggable time source for per-request/stage phase timestamps
         # (duck-typed Clock: .now()).  None keeps the historical wall
         # clock; a VirtualClock makes every recorded phase a *trace*
@@ -650,6 +703,7 @@ class DSIPipeline:
         # on wall time regardless.
         self._now = time.monotonic if clock is None else clock.now
         self._clock = clock
+        self.times = StageTimes(now=self._now)
         # cross-job single-flight table (service-level; None for bare
         # service doubles in tests) — consulted before producing a miss
         self._production = getattr(self.svc, "production", None)
@@ -826,139 +880,157 @@ class DSIPipeline:
         the h2d EWMA feeding the CALIBRATABLE ``b_hbm`` and the fused
         stage times would measure dispatch latency, not the transfer or
         compute, and mis-steer MDP repartitioning.
+
+        The whole call and each of its phases run in a span of
+        :class:`StageTimes` (``self.times``); the per-sample lookups and
+        encoded admissions are its counters.
         """
         import jax
         import jax.numpy as jnp
 
         from repro.kernels.augment.ops import (augment_batch_seeded,
                                                decode_augment_batch_seeded)
-        tel = self.telemetry
-        ids, _forms = self.session.next_batch_ids()
-        epoch_tag = self.session.epoch
-        rows: List = [None] * len(ids)
-        enc_group: List[Tuple[int, int, bytes]] = []   # (slot, sid, payload)
-        dec_group: List[Tuple[int, int, np.ndarray]] = []
-        dec_dev_group: List[Tuple[int, int, object]] = []  # HBM decoded hits
-        for slot, sid_ in enumerate(ids):
-            sid = int(sid_)
-            t_look = self._now()
-            form, value, tier = self.session.lookup_tiered(sid)
-            tel.record_serve(form)
-            t0 = self._now()
-            if form is None:
-                enc = self.storage.fetch(sid)
-                dt = self._now() - t0
-                self.times.fetch += dt
-                tel.record_stage("fetch_storage", dt)
-                tel.record_bytes("storage", len(enc), dt)
-                self.session.admit(sid, "encoded", enc, len(enc))
-                enc_group.append((slot, sid, enc))
-                continue
-            self.times.fetch += t0 - t_look
-            tel.record_stage("fetch_cache", t0 - t_look)
-            if form == "augmented" and tier == "hbm":
-                # zero-copy device serve: no h2d traffic at all
-                rows[slot] = value
-                continue
-            channel = "disk" if tier == "disk" else "cache"
-            if form == "augmented":
-                host = np.asarray(value)
-                tel.record_bytes(channel, host.nbytes, t0 - t_look)
-                t1 = self._now()
-                rows[slot] = jax.block_until_ready(jnp.asarray(host))
-                tel.record_bytes("h2d", host.nbytes,
-                                 self._now() - t1)
-            elif form == "decoded":
-                if tier == "hbm":
-                    # device-resident decoded hit: augment on device —
-                    # no host round-trip, so no byte-channel record (a
-                    # d2h download metered as "cache" would skew b_cache)
-                    dec_dev_group.append((slot, sid, value))
-                else:
-                    img = np.asarray(value)
-                    tel.record_bytes(channel, img.nbytes, t0 - t_look)
-                    dec_group.append((slot, sid, img))
-            else:                                      # encoded cache hit
-                tel.record_bytes(channel, len(value), t0 - t_look)
-                enc_group.append((slot, sid, value))
-        fresh: List[Tuple[int, object]] = []           # (sid, device row)
-        if enc_group:
-            sids = [sid for _s, sid, _p in enc_group]
-            seeds = np.asarray([_aug_seed(epoch_tag, sid) for sid in sids],
-                               np.int64)
-            t1 = self._now()
-            out = jax.block_until_ready(decode_augment_batch_seeded(
-                [p for _s, _sid, p in enc_group], sids, seeds,
-                ds_seed=self._fused_seed, image_hw=self.ds.image_hw,
-                crop_h=self.ds.crop_hw[0], crop_w=self.ds.crop_hw[1]))
-            dt = self._now() - t1
-            # one fused launch covers both stages; split its time evenly
-            # so the calibrated t_da = conc/(decode+augment) lands on
-            # the fused rate
-            self.times.decode += dt / 2
-            self.times.augment += dt / 2
-            tel.record_stage("decode", dt / 2, n=len(enc_group))
-            tel.record_stage("augment", dt / 2, n=len(enc_group))
-            for i, (slot, sid, _p) in enumerate(enc_group):
-                rows[slot] = out[i]
-                fresh.append((sid, out[i]))
-        if dec_group:
-            sids = [sid for _s, sid, _img in dec_group]
-            imgs = np.stack([img for _s, _sid, img in dec_group])
-            seeds = np.asarray([_aug_seed(epoch_tag, sid) for sid in sids],
-                               np.int64)
-            t1 = self._now()
-            out = jax.block_until_ready(
-                augment_batch_seeded(imgs, seeds, *self.ds.crop_hw,
-                                     as_device=True))
-            dt = self._now() - t1
-            self.times.augment += dt
-            tel.record_stage("augment", dt, n=len(dec_group))
-            # decoded pixels shipped up for the device-side augment
-            tel.record_bytes("h2d", imgs.nbytes, dt)
-            for i, (slot, sid, _img) in enumerate(dec_group):
-                rows[slot] = out[i]
-                fresh.append((sid, out[i]))
-        if dec_dev_group:
-            sids = [sid for _s, sid, _img in dec_dev_group]
-            imgs_dev = jnp.stack([img for _s, _sid, img in dec_dev_group])
-            seeds = np.asarray([_aug_seed(epoch_tag, sid) for sid in sids],
-                               np.int64)
-            t1 = self._now()
-            out = jax.block_until_ready(
-                augment_batch_seeded(imgs_dev, seeds, *self.ds.crop_hw,
-                                     as_device=True))
-            dt = self._now() - t1
-            self.times.augment += dt
-            tel.record_stage("augment", dt, n=len(dec_dev_group))
-            # pixels were already device-resident: no h2d traffic
-            for i, (slot, sid, _img) in enumerate(dec_dev_group):
-                rows[slot] = out[i]
-                fresh.append((sid, out[i]))
-        # admit the freshly augmented device rows: HBM-first put routing
-        # keeps them device-resident; without a device tier admit host
-        # copies so a DRAM slot never pins a jax buffer
-        if fresh and self.svc.tier_capacity("augmented") > 0:
-            wanted = self.svc.admission_votes("augmented",
-                                              [sid for sid, _r in fresh])
-            entries = [(sid, row if self.svc.has_hbm else np.asarray(row),
-                        int(row.nbytes))
-                       for (sid, row), w in zip(fresh, wanted) if w]
-            if entries:
-                self.session.admit_batch("augmented", entries)
-        t0 = self._now()
-        batch = {
-            "images": jnp.stack(rows).astype(jnp.float32),
-            "labels": np.asarray([self.ds.label(int(s)) for s in ids],
-                                 np.int32),
-            "ids": np.asarray(ids, np.int64),
-        }
-        dt = self._now() - t0
-        self.times.collate += dt
-        tel.record_stage("collate", dt, n=len(ids))
-        self.times.batches += 1
-        self._process_refills()
-        self.svc.maybe_repartition()
+        tel, times = self.telemetry, self.times
+        with times.span("next_batch"):
+            with times.span("sample"):
+                ids, _forms = self.session.next_batch_ids()
+            epoch_tag = self.session.epoch
+            rows: List = [None] * len(ids)
+            # (slot, sid, value): encoded payloads, host decoded images
+            # and HBM decoded images, each augmented in one launch
+            enc_group: List[Tuple[int, int, bytes]] = []
+            dec_group: List[Tuple[int, int, np.ndarray]] = []
+            dec_dev_group: List[Tuple[int, int, object]] = []
+            with times.span("gather"):
+                for slot, sid_ in enumerate(ids):
+                    sid = int(sid_)
+                    t_look = self._now()
+                    form, value, tier = self.session.lookup_tiered(sid)
+                    tel.record_serve(form)
+                    t0 = self._now()
+                    times.lookup += t0 - t_look
+                    if form is None:
+                        enc = self.storage.fetch(sid)
+                        t1 = self._now()
+                        self.session.admit(sid, "encoded", enc, len(enc))
+                        times.admit += self._now() - t1
+                        dt = t1 - t0
+                        times.fetch += dt
+                        tel.record_stage("fetch_storage", dt)
+                        tel.record_bytes("storage", len(enc), dt)
+                        enc_group.append((slot, sid, enc))
+                        continue
+                    times.fetch += t0 - t_look
+                    tel.record_stage("fetch_cache", t0 - t_look)
+                    if form == "augmented" and tier == "hbm":
+                        # zero-copy device serve: no h2d traffic at all
+                        rows[slot] = value
+                        continue
+                    channel = "disk" if tier == "disk" else "cache"
+                    if form == "augmented":
+                        host = np.asarray(value)
+                        tel.record_bytes(channel, host.nbytes, t0 - t_look)
+                        t1 = self._now()
+                        rows[slot] = jax.block_until_ready(jnp.asarray(host))
+                        tel.record_bytes("h2d", host.nbytes,
+                                         self._now() - t1)
+                    elif form == "decoded":
+                        if tier == "hbm":
+                            # device-resident decoded hit: augment on
+                            # device — no host round-trip, so no
+                            # byte-channel record (a d2h download
+                            # metered as "cache" would skew b_cache)
+                            dec_dev_group.append((slot, sid, value))
+                        else:
+                            img = np.asarray(value)
+                            tel.record_bytes(channel, img.nbytes,
+                                             t0 - t_look)
+                            dec_group.append((slot, sid, img))
+                    else:                              # encoded cache hit
+                        tel.record_bytes(channel, len(value), t0 - t_look)
+                        enc_group.append((slot, sid, value))
+            fresh: List[Tuple[int, object]] = []       # (sid, device row)
+
+            def place(group, out):
+                with times.span("rows"):
+                    for i, (slot, sid, _v) in enumerate(group):
+                        rows[slot] = out[i]
+                        fresh.append((sid, out[i]))
+
+            if enc_group:
+                with times.span("fused"):
+                    sids = [sid for _s, sid, _p in enc_group]
+                    seeds = np.asarray(
+                        [_aug_seed(epoch_tag, sid) for sid in sids], np.int64)
+                    t1 = self._now()
+                    out = jax.block_until_ready(decode_augment_batch_seeded(
+                        [p for _s, _sid, p in enc_group], sids, seeds,
+                        ds_seed=self._fused_seed, image_hw=self.ds.image_hw,
+                        crop_h=self.ds.crop_hw[0], crop_w=self.ds.crop_hw[1]))
+                    dt = self._now() - t1
+                # one fused launch covers both stages; split the kernel
+                # call's time evenly so the calibrated
+                # t_da = conc/(decode+augment) lands on the fused rate
+                tel.record_stage("decode", dt / 2, n=len(enc_group))
+                tel.record_stage("augment", dt / 2, n=len(enc_group))
+                place(enc_group, out)
+            # an augment span holds its group's stacking and seeds too;
+            # telemetry gets the kernel call alone
+            if dec_group:
+                with times.span("augment"):
+                    sids = [sid for _s, sid, _img in dec_group]
+                    imgs = np.stack([img for _s, _sid, img in dec_group])
+                    seeds = np.asarray(
+                        [_aug_seed(epoch_tag, sid) for sid in sids], np.int64)
+                    t1 = self._now()
+                    out = jax.block_until_ready(
+                        augment_batch_seeded(imgs, seeds, *self.ds.crop_hw,
+                                             as_device=True))
+                    dt = self._now() - t1
+                tel.record_stage("augment", dt, n=len(dec_group))
+                # decoded pixels shipped up for the device-side augment
+                tel.record_bytes("h2d", imgs.nbytes, dt)
+                place(dec_group, out)
+            if dec_dev_group:
+                with times.span("augment"):
+                    sids = [sid for _s, sid, _img in dec_dev_group]
+                    imgs_dev = jnp.stack(
+                        [img for _s, _sid, img in dec_dev_group])
+                    seeds = np.asarray(
+                        [_aug_seed(epoch_tag, sid) for sid in sids], np.int64)
+                    t1 = self._now()
+                    out = jax.block_until_ready(
+                        augment_batch_seeded(imgs_dev, seeds,
+                                             *self.ds.crop_hw,
+                                             as_device=True))
+                    dt = self._now() - t1
+                # pixels were already device-resident: no h2d traffic
+                tel.record_stage("augment", dt, n=len(dec_dev_group))
+                place(dec_dev_group, out)
+            # admit the freshly augmented device rows: HBM-first put
+            # routing keeps them device-resident; without a device tier
+            # admit host copies so a DRAM slot never pins a jax buffer
+            with times.span("admit_rows"):
+                if fresh and self.svc.tier_capacity("augmented") > 0:
+                    wanted = self.svc.admission_votes(
+                        "augmented", [sid for sid, _r in fresh])
+                    entries = [(sid, row if self.svc.has_hbm
+                                else np.asarray(row), int(row.nbytes))
+                               for (sid, row), w in zip(fresh, wanted) if w]
+                    if entries:
+                        self.session.admit_batch("augmented", entries)
+            with times.span("collate") as collate:
+                batch = {
+                    "images": jnp.stack(rows).astype(jnp.float32),
+                    "labels": np.asarray(
+                        [self.ds.label(int(s)) for s in ids], np.int32),
+                    "ids": np.asarray(ids, np.int64),
+                }
+            tel.record_stage("collate", collate.dt, n=len(ids))
+            times.batches += 1
+            with times.span("upkeep"):
+                self._process_refills()
+                self.svc.maybe_repartition()
         return batch
 
     def _process_refills(self, max_n: int = 32) -> None:

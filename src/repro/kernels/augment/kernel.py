@@ -87,6 +87,7 @@ def augment(images: jax.Array, tops: jax.Array, lefts: jax.Array,
     kernel = functools.partial(_augment_kernel, crop_w=crop_w)
     out = pl.pallas_call(
         kernel,
+        name="augment_kernel",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B, crop_h // rows),
             in_specs=[pl.BlockSpec((1, H, W * 3),

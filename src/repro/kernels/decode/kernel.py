@@ -50,6 +50,7 @@ def decode(bases: jax.Array, mixes: jax.Array, *, h: int, w: int,
     rows = row_tile(h, 32)
     out = pl.pallas_call(
         _decode_kernel,
+        name="decode_kernel",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B, h // rows), in_specs=[],
             out_specs=pl.BlockSpec((1, rows, w * 3),
@@ -97,6 +98,7 @@ def decode_augment(bases: jax.Array, mixes: jax.Array, tops: jax.Array,
                                crop_w=crop_w)
     out = pl.pallas_call(
         kernel,
+        name="decode_augment_kernel",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5, grid=(B, crop_h // rows), in_specs=[],
             out_specs=pl.BlockSpec((1, rows, crop_w * 3),

@@ -123,9 +123,10 @@ def image_batch_source(model, batch: int, seed: int = 0,
 
     def next_batch():
         raw = pipe.next_batch()
-        return {"patch_embeds": patchify_stub(raw["images"], T, d),
-                "labels": jnp.asarray(raw["labels"] % n_classes,
-                                      jnp.int32)}
+        with pipe.times.span("patchify"):
+            return {"patch_embeds": patchify_stub(raw["images"], T, d),
+                    "labels": jnp.asarray(raw["labels"] % n_classes,
+                                          jnp.int32)}
 
     return next_batch, pipe, server
 

@@ -29,15 +29,22 @@ def build_train_step(model: Model, parallel: ParallelismConfig,
     remat = parallel.remat
     n_micro = parallel.microbatches
 
-    def loss_fn(params, mb):
-        return model.loss(params, mb, remat=remat)
+    def loss_and_grads(params, mb):
+        # value_and_grad as a vjp, so that the profiler's op names tell
+        # the forward pass from the backward one
+        with jax.named_scope("forward"):
+            loss, pullback = jax.vjp(
+                lambda p: model.loss(p, mb, remat=remat), params)
+        with jax.named_scope("backward"):
+            (grads,) = pullback(jnp.ones_like(loss))
+        return loss, grads
 
     def step(params, opt_state: AdamWState, batch):
         if n_micro > 1:
             mbs = _split_microbatches(batch, n_micro)
 
             def acc(carry, mb):
-                loss, g = jax.value_and_grad(loss_fn)(params, mb)
+                loss, g = loss_and_grads(params, mb)
                 return jax.tree.map(jnp.add, carry, g), loss
 
             zero = jax.tree.map(
@@ -46,8 +53,10 @@ def build_train_step(model: Model, parallel: ParallelismConfig,
             grads = jax.tree.map(lambda g: g / n_micro, grads)
             loss = jnp.mean(losses)
         else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        new_params, new_state, gnorm = opt.update(grads, opt_state, params)
+            loss, grads = loss_and_grads(params, batch)
+        with jax.named_scope("optimizer"):
+            new_params, new_state, gnorm = opt.update(grads, opt_state,
+                                                      params)
         metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gnorm}
         return new_params, new_state, metrics
 
