@@ -53,6 +53,7 @@ style still works as a deprecated shim that opens a session internally.
 """
 from __future__ import annotations
 
+import functools
 import logging
 import queue
 import threading
@@ -127,7 +128,9 @@ class StageTimes:
     ``sample``, ``gather``, ``fused``, ``augment``, ``rows``,
     ``admit_rows``, ``collate`` and ``upkeep``) and, inside ``gather``,
     sums per-sample counters: ``lookup`` (every tiered lookup) and
-    ``admit`` (encoded admissions).
+    ``admit`` (encoded admissions).  Two counts go with them:
+    ``assembles`` (launches of the program that assembles a batch) and
+    ``row_slices`` (rows cut out of a group's output for admission).
     ``patchify`` is the image feed's span (``launch/train.py``)."""
     fetch: float = 0.0
     decode: float = 0.0
@@ -144,6 +147,8 @@ class StageTimes:
     patchify: float = 0.0
     lookup: float = 0.0
     admit: float = 0.0
+    assembles: int = 0
+    row_slices: int = 0
     now: Callable[[], float] = field(default=time.monotonic, repr=False,
                                      compare=False)
 
@@ -641,6 +646,46 @@ class _StageParallelExecutor:
         self.pipe.telemetry.clear_stage_workers("decode", "augment")
 
 
+def _assemble(groups, singles, order):
+    """The group outputs, then the single rows, concatenated and
+    gathered by ``order`` into slot order, as float32."""
+    import jax.numpy as jnp
+    parts = list(groups) + ([jnp.stack(singles)] if singles else [])
+    rows = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    return jnp.take(rows, order, axis=0, mode="clip").astype(jnp.float32)
+
+
+@functools.lru_cache(maxsize=None)
+def _assemble_program():
+    import jax
+    return jax.jit(_assemble)
+
+
+def _assemble_images(groups: List[Tuple[List[int], object]],
+                     singles: List[Tuple[int, object]],
+                     n: int) -> Tuple[object, int]:
+    """The device route's ``(n, crop_h, crop_w, 3)`` float32 batch from
+    its sources: ``groups`` of (slots, output rows in that order) and
+    ``singles`` of (slot, one row).  Returns it with the number of
+    programs launched: none where one group fills every slot in slot
+    order (its output is the batch), else one.  A single row may be a
+    cache tier's own buffer, so the batch is then always a new one."""
+    if not singles and len(groups) == 1:
+        slots, out = groups[0]
+        if slots == list(range(n)) and out.dtype == np.float32:
+            return out, 0
+    order = np.empty(n, np.int32)
+    pos = 0
+    for slots, _out in groups:
+        order[slots] = np.arange(pos, pos + len(slots))
+        pos += len(slots)
+    order[[slot for slot, _row in singles]] = np.arange(
+        pos, pos + len(singles))
+    images = _assemble_program()(tuple(out for _s, out in groups),
+                                 tuple(row for _s, row in singles), order)
+    return images, 1
+
+
 class DSIPipeline:
     """Per-session pipeline over a shared Seneca service + RemoteStorage."""
 
@@ -869,9 +914,12 @@ class DSIPipeline:
         """One batch through the device route: fused decode+augment for
         encoded samples, zero-copy serve for HBM hits, device collate.
 
-        Every sample ends as a device row; the only host→device payload
-        traffic (metered on the ``"h2d"`` channel) is DRAM/disk-cached
-        values being uploaded.  Encoded samples never materialize a host
+        Each group's output and each single row (an HBM hit, or an
+        uploaded DRAM/disk hit) is a source of the batch, assembled in
+        slot order by at most one program launch
+        (:func:`_assemble_images`); the only host→device payload traffic
+        (metered on the ``"h2d"`` channel) is DRAM/disk-cached values
+        being uploaded.  Encoded samples never materialize a host
         decoded image — the fused kernel ships per-sample scalars only —
         so (by design) this route admits no "decoded" forms.
 
@@ -895,7 +943,10 @@ class DSIPipeline:
             with times.span("sample"):
                 ids, _forms = self.session.next_batch_ids()
             epoch_tag = self.session.epoch
-            rows: List = [None] * len(ids)
+            # the batch's sources: (slots, output) of each augmented
+            # group and (slot, row) of each row served as it is
+            groups: List[Tuple[List[int], object]] = []
+            singles: List[Tuple[int, object]] = []
             # (slot, sid, value): encoded payloads, host decoded images
             # and HBM decoded images, each augmented in one launch
             enc_group: List[Tuple[int, int, bytes]] = []
@@ -924,14 +975,15 @@ class DSIPipeline:
                     tel.record_stage("fetch_cache", t0 - t_look)
                     if form == "augmented" and tier == "hbm":
                         # zero-copy device serve: no h2d traffic at all
-                        rows[slot] = value
+                        singles.append((slot, value))
                         continue
                     channel = "disk" if tier == "disk" else "cache"
                     if form == "augmented":
                         host = np.asarray(value)
                         tel.record_bytes(channel, host.nbytes, t0 - t_look)
                         t1 = self._now()
-                        rows[slot] = jax.block_until_ready(jnp.asarray(host))
+                        singles.append(
+                            (slot, jax.block_until_ready(jnp.asarray(host))))
                         tel.record_bytes("h2d", host.nbytes,
                                          self._now() - t1)
                     elif form == "decoded":
@@ -949,13 +1001,14 @@ class DSIPipeline:
                     else:                              # encoded cache hit
                         tel.record_bytes(channel, len(value), t0 - t_look)
                         enc_group.append((slot, sid, value))
-            fresh: List[Tuple[int, object]] = []       # (sid, device row)
+            # (sid, group output, row): rows a group augmented afresh
+            fresh: List[Tuple[int, object, int]] = []
 
             def place(group, out):
                 with times.span("rows"):
-                    for i, (slot, sid, _v) in enumerate(group):
-                        rows[slot] = out[i]
-                        fresh.append((sid, out[i]))
+                    groups.append(([slot for slot, _sid, _v in group], out))
+                    fresh.extend((sid, out, i)
+                                 for i, (_slot, sid, _v) in enumerate(group))
 
             if enc_group:
                 with times.span("fused"):
@@ -1007,26 +1060,36 @@ class DSIPipeline:
                 # pixels were already device-resident: no h2d traffic
                 tel.record_stage("augment", dt, n=len(dec_dev_group))
                 place(dec_dev_group, out)
-            # admit the freshly augmented device rows: HBM-first put
-            # routing keeps them device-resident; without a device tier
-            # admit host copies so a DRAM slot never pins a jax buffer
+            # admit the freshly augmented rows admission votes in, each
+            # cut out of its group's output as a buffer of its own:
+            # HBM-first put routing keeps them device-resident; without
+            # a device tier admit host copies so a DRAM slot never pins
+            # a jax buffer
             with times.span("admit_rows"):
                 if fresh and self.svc.tier_capacity("augmented") > 0:
                     wanted = self.svc.admission_votes(
-                        "augmented", [sid for sid, _r in fresh])
-                    entries = [(sid, row if self.svc.has_hbm
-                                else np.asarray(row), int(row.nbytes))
-                               for (sid, row), w in zip(fresh, wanted) if w]
+                        "augmented", [sid for sid, _o, _i in fresh])
+                    entries = []
+                    for (sid, out, i), w in zip(fresh, wanted):
+                        if w:
+                            row = out[i]
+                            entries.append(
+                                (sid, row if self.svc.has_hbm
+                                 else np.asarray(row), int(row.nbytes)))
+                    times.row_slices += len(entries)
                     if entries:
                         self.session.admit_batch("augmented", entries)
             with times.span("collate") as collate:
+                images, launches = _assemble_images(groups, singles,
+                                                    len(ids))
                 batch = {
-                    "images": jnp.stack(rows).astype(jnp.float32),
+                    "images": images,
                     "labels": np.asarray(
                         [self.ds.label(int(s)) for s in ids], np.int32),
                     "ids": np.asarray(ids, np.int64),
                 }
             tel.record_stage("collate", collate.dt, n=len(ids))
+            times.assembles += launches
             times.batches += 1
             with times.span("upkeep"):
                 self._process_refills()

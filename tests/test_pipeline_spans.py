@@ -76,7 +76,9 @@ def test_as_dict_keeps_its_old_keys_and_adds_seconds():
     assert "now" not in d
     assert set(CHILDREN) | {"next_batch", "patchify", "lookup",
                             "admit"} <= set(d)
-    assert all(isinstance(v, float) for k, v in d.items() if k != "batches")
+    counts = ("batches", "assembles", "row_slices")
+    assert all(isinstance(d[k], int) for k in counts)
+    assert all(isinstance(v, float) for k, v in d.items() if k not in counts)
 
 
 def test_importing_the_pipeline_does_not_import_jax():
