@@ -8,6 +8,10 @@ benchmark's directory, so a new one is a new file plus a new entry:
   and the limits of the correctness check;
 * ``traffic/<traffic>.json``: the dataset the pipeline serves and the
   cache tier in front of it;
+* ``reference/<reference>.py``: the plain reference a configuration
+  names under ``"reference"``, with ``check_steps(config, traffic,
+  seeds, rows, ids, *, precision="f32", use_rows=0)``, which follows
+  the set-up steps, and ``train_flops_per_sample(sizes)``;
 * ``metrics/<metric>.py``: a reader with ``read(run) -> float | None``.
 """
 from __future__ import annotations
@@ -16,6 +20,7 @@ import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional, Sequence
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
@@ -40,6 +45,7 @@ class Cell:
     end_to_end: List[Metric]
     per_layer: List[Metric]
     run_seconds: int
+    reference: Optional[ModuleType] = None
 
 
 def _load_json(path: Path) -> Dict:
@@ -47,12 +53,16 @@ def _load_json(path: Path) -> Dict:
         return json.load(f)
 
 
-def load_reader(path: Path, name: str) -> Callable:
+def load_module(path: Path, kind: str, name: str) -> ModuleType:
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(path: Path, name: str) -> Callable:
+    return load_module(path, "metric", name).read
 
 
 class Catalog:
@@ -78,6 +88,21 @@ class Catalog:
     def traffic(self, name: str) -> Dict:
         return _load_json(self.find("traffic", f"{name}.json"))
 
+    def reference(self, config: Dict) -> ModuleType:
+        """The module ``reference/<name>.py`` that ``config["reference"]``
+        names; a configuration without one cannot be checked."""
+        name = config.get("reference")
+        try:
+            path = self.find("reference", f"{name}.py") if name else None
+        except FileNotFoundError:
+            path = None
+        if path is None:
+            raise LookupError(
+                f"configuration {config.get('name')!r} names reference "
+                f"{name!r}; no reference/<name>.py for it in "
+                f"{[str(d) for d in self.dirs]}")
+        return load_module(path, "reference", name)
+
     def _metric(self, entry: Dict) -> Metric:
         return Metric(entry["name"], entry["unit"], entry["better"],
                       entry["source"],
@@ -96,8 +121,9 @@ class Catalog:
         layer = [m for m in self.spec["per_layer"]
                  if (workload in m["workloads"] if "workloads" in m
                      else m["moves"] in e2e_names)]
-        return Cell(w["name"], int(w["chips"]), self.config(w["config"]),
+        config = self.config(w["config"])
+        return Cell(w["name"], int(w["chips"]), config,
                     self.traffic(w["traffic"]),
                     [self._metric(m) for m in e2e],
                     [self._metric(m) for m in layer],
-                    int(self.spec["run_seconds"]))
+                    int(self.spec["run_seconds"]), self.reference(config))

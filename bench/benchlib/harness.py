@@ -11,6 +11,7 @@ window the program's state is freed and the reference runs.
 from __future__ import annotations
 
 import contextlib
+import functools
 import gc
 import json
 import math
@@ -123,8 +124,10 @@ def _by_name(tree) -> Dict:
 
 
 def _check_sizes(model, sizes: Dict) -> None:
+    """The program's model has the configuration's sizes; a dotted key
+    (``moe.n_experts``) names a field of a nested configuration."""
     cfg = model.cfg
-    got = {k: getattr(cfg, k) for k in sizes}
+    got = {k: functools.reduce(getattr, k.split("."), cfg) for k in sizes}
     if got != sizes:
         raise ValueError(f"the program's model {got} is not the "
                          f"configuration's {sizes}")
@@ -269,30 +272,6 @@ def check_rows(entries: List[Dict], traffic: Dict, data_seed: int):
     return worst, label_errors, refs
 
 
-def reference_steps(config: Dict, traffic: Dict, key_seed: int,
-                    ref_rows: List[np.ndarray], ids: List[np.ndarray],
-                    precision: str = "f32",
-                    use_rows: int = 0) -> Dict:
-    from reference import synthetic_images as ref_data
-    from reference import vit_encoder as ref
-    sz = sizes_of(config)
-    o = config["optimizer"]
-    hp = {k: o[k] for k in ("lr", "b1", "b2", "eps", "weight_decay",
-                            "grad_clip", "warmup_steps", "total_steps",
-                            "lr_floor")}
-    batches = []
-    for rows, sid in zip(ref_rows, ids):
-        emb = ref.patch_embeds(rows.astype(np.float32), sz["frontend_tokens"],
-                               sz["d_model"])
-        labels = np.asarray(
-            [ref_data.label(int(s), int(traffic["dataset"]["n_classes"]))
-             % sz["n_classes"] for s in sid], np.int32)
-        batches.append((emb, labels))
-    return ref.train_steps(sz | {"norm_eps": config["sizes"]["norm_eps"]},
-                           hp, key_seed, batches, precision=precision,
-                           use_rows=use_rows)
-
-
 def _served_counts(before: Dict, after: Dict) -> Dict[str, int]:
     b = before["telemetry"]["serve_counts"]
     a = after["telemetry"]["serve_counts"]
@@ -309,9 +288,9 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool,
     if device is None:
         device = device_record(cell.chips)
     s = system.seeds(seed)
-    sz = sizes_of(cell.config)
     record = RunRecord(cell, int(cell.config["batch"]),
-                       yardstick.train_flops_per_sample(sz),
+                       cell.reference.train_flops_per_sample(
+                           sizes_of(cell.config)),
                        yardstick.PEAKS.get(device["kind"], {}), 0.0)
 
     sys_, prog, setup_batches = setup(cell, seed, step_builder)
@@ -352,8 +331,8 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool,
     t_ref = time.perf_counter()
     row_gap, label_errors, ref_rows = check_rows(
         entries, cell.traffic, s["data"])
-    ref_steps = reference_steps(
-        cell.config, cell.traffic, s["params"], ref_rows[:SETUP_STEPS],
+    ref_steps = cell.reference.check_steps(
+        cell.config, cell.traffic, s, ref_rows[:SETUP_STEPS],
         [e["ids"] for e in setup_batches])
     numbers = check.step_numbers(prog, ref_steps)
     for k in ref_steps["change"]:

@@ -24,9 +24,17 @@ def seeds(seed: int) -> Dict[str, int]:
 
 
 def model_config(config: Dict):
+    """The program's ``base`` configuration with the file's overrides.  An
+    override that is a dict, of a field that holds a dataclass (``{"moe":
+    {"n_experts": 8}}``), replaces only the keys it names in that field."""
     from repro.configs import registry
     m = config["model"]
-    return dataclasses.replace(registry.get(m["base"]), **m["overrides"])
+    base = registry.get(m["base"])
+    over = {k: dataclasses.replace(getattr(base, k), **v)
+            if isinstance(v, dict)
+            and dataclasses.is_dataclass(getattr(base, k, None)) else v
+            for k, v in m["overrides"].items()}
+    return dataclasses.replace(base, **over)
 
 
 def dataset(traffic: Dict, data_seed: int):

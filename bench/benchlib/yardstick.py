@@ -1,6 +1,8 @@
-"""The yardstick: chip peaks, and the work a step or a kernel call must do.
+"""The yardstick: chip peaks, and the bytes a kernel call of the data
+path must move.
 
-Counts come from the configuration's shapes, never from the program.
+Counts come from the traffic's shapes, never from the program; a
+model's FLOPs a sample are its reference's (``reference/<name>.py``).
 """
 from __future__ import annotations
 
@@ -20,22 +22,6 @@ def peaks(device_kind: str) -> Dict[str, float]:
         raise KeyError(f"no published peaks for device kind "
                        f"{device_kind!r}; known: {sorted(PEAKS)}")
     return PEAKS[device_kind]
-
-
-def train_flops_per_sample(sizes: Dict) -> float:
-    """Model FLOPs of one training sample: forward plus backward (twice
-    the forward), recomputation not counted.
-
-    Per token and layer the forward multiplies by every matmul parameter
-    once (4 d^2 for q, k, v, o and 3 d f for the gated MLP, 2 FLOPs each)
-    and attends to all T tokens (2 T d for the scores, 2 T d for the
-    weighted sum; the encoder is not causal).  The head runs on token 0
-    only (2 d C)."""
-    L, d, f = sizes["n_layers"], sizes["d_model"], sizes["d_ff"]
-    T, C = sizes["frontend_tokens"], sizes["n_classes"]
-    per_token_layer = 2 * (4 * d * d + 3 * d * f) + 4 * T * d
-    forward = T * L * per_token_layer + 2 * d * C
-    return 3.0 * forward
 
 
 def decode_augment_bytes(batch: int, crop_hw, out_itemsize: int = 4,

@@ -61,8 +61,8 @@ def readings(cell, seed: int) -> dict:
     ref = {}
     for name, kw in runs.items():
         t0 = time.perf_counter()
-        ref[name] = harness.reference_steps(cell.config, cell.traffic,
-                                            s["params"], ref_rows, ids, **kw)
+        ref[name] = cell.reference.check_steps(cell.config, cell.traffic, s,
+                                               ref_rows, ids, **kw)
         out[f"seconds_{name}"] = time.perf_counter() - t0
     out["program"] = check.step_numbers(prog, ref["f32"])
     out["control_fp8"] = check.step_numbers(ref["fp8"], ref["f32"])

@@ -1,6 +1,7 @@
-"""Splitting each group's device output into one row a sample (the span
-``rows``: ``out[i]`` for every slot, kept for the batch and for
-admission) per batch served in the window, in ms."""
+"""Slot bookkeeping after each group's device output (the span ``rows``:
+the group's slots noted beside its output, which stays whole, and each
+of its rows listed for admission, whose slices ``admit_rows`` then
+cuts) per batch served in the window, in ms."""
 
 KEYS = ("rows",)
 

@@ -16,6 +16,10 @@ array of rank two or more.
 ``precision="fp8"`` computes every matmul on operands rounded to
 float8 (e4m3 forward, e5m2 for the incoming gradient) with one scale
 per tensor: the control that the comparison has to refuse.
+
+The benchmark reaches this module through a configuration's
+``"reference": "vit_encoder"`` and calls ``check_steps`` and
+``train_flops_per_sample``.
 """
 from __future__ import annotations
 
@@ -273,3 +277,46 @@ def train_steps(sizes: Dict, hp: Dict, key_seed: int, batches, *,
     change = {k: float(_diff_norm(p[k], initial[k])) for k in p}
     out.update(losses=losses, change=change)
     return out
+
+
+# -- what the benchmark calls ---------------------------------------------
+
+def check_steps(config: Dict, traffic: Dict, seeds: Dict, rows, ids, *,
+                precision: str = "f32", use_rows: int = 0) -> Dict:
+    """The set-up steps of a run, from the seed's parameters, over the
+    reference's rows (``rows``, one array a step) of the sample ``ids``:
+    as ``train_steps`` returns them.  Each batch is the rows' patch
+    embedding and each sample's label folded into the head's classes;
+    the optimizer is the configuration's."""
+    from reference import synthetic_images as ref_data
+    sz = dict(config["sizes"])
+    o = config["optimizer"]
+    hp = {k: o[k] for k in ("lr", "b1", "b2", "eps", "weight_decay",
+                            "grad_clip", "warmup_steps", "total_steps",
+                            "lr_floor")}
+    batches = []
+    for r, sid in zip(rows, ids):
+        emb = patch_embeds(r.astype(np.float32), sz["frontend_tokens"],
+                           sz["d_model"])
+        labels = np.asarray(
+            [ref_data.label(int(s), int(traffic["dataset"]["n_classes"]))
+             % sz["n_classes"] for s in sid], np.int32)
+        batches.append((emb, labels))
+    return train_steps(sz, hp, seeds["params"], batches,
+                       precision=precision, use_rows=use_rows)
+
+
+def train_flops_per_sample(sizes: Dict) -> float:
+    """Model FLOPs of one training sample: forward plus backward (twice
+    the forward), recomputation not counted.
+
+    Per token and layer the forward multiplies by every matmul parameter
+    once (4 d^2 for q, k, v, o and 3 d f for the gated MLP, 2 FLOPs each)
+    and attends to all T tokens (2 T d for the scores, 2 T d for the
+    weighted sum; the encoder is not causal).  The head runs on token 0
+    only (2 d C)."""
+    L, d, f = sizes["n_layers"], sizes["d_model"], sizes["d_ff"]
+    T, C = sizes["frontend_tokens"], sizes["n_classes"]
+    per_token_layer = 2 * (4 * d * d + 3 * d * f) + 4 * T * d
+    forward = T * L * per_token_layer + 2 * d * C
+    return 3.0 * forward

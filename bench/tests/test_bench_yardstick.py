@@ -1,4 +1,6 @@
-"""The yardstick's counts against hand counts."""
+"""The counts the benchmark's numbers rest on, against hand counts: the
+yardstick's peaks and kernel bytes, and the FLOPs a sample of each
+configuration's reference."""
 import json
 import os
 
@@ -6,11 +8,19 @@ import benchpath  # noqa: F401
 import pytest
 
 from benchlib import yardstick
+from benchlib.catalog import Catalog
 
 
-def _sizes(name):
+def _config(name):
     with open(os.path.join(benchpath.BENCH, "configs", f"{name}.json")) as f:
-        return json.load(f)["sizes"]
+        return json.load(f)
+
+
+def train_flops_per_sample(name):
+    """The FLOPs a sample of the reference the configuration names."""
+    config = _config(name)
+    return Catalog().reference(config).train_flops_per_sample(
+        config["sizes"])
 
 
 @pytest.mark.parametrize("name, per_sample", [
@@ -21,15 +31,13 @@ def _sizes(name):
     ("vit-huge", 3 * 336_872_181_760),
 ])
 def test_train_flops_per_sample_matches_hand_count(name, per_sample):
-    assert yardstick.train_flops_per_sample(_sizes(name)) == per_sample
+    assert train_flops_per_sample(name) == per_sample
 
 
 def test_flops_are_about_the_published_ratio():
     # ~138 GFLOP for ViT-B/16 and ~1.01 TFLOP for ViT-H a sample
-    assert round(yardstick.train_flops_per_sample(_sizes("vit-base-16"))
-                 / 1e9) == 138
-    assert round(yardstick.train_flops_per_sample(_sizes("vit-huge"))
-                 / 1e10) == 101
+    assert round(train_flops_per_sample("vit-base-16") / 1e9) == 138
+    assert round(train_flops_per_sample("vit-huge") / 1e10) == 101
 
 
 def test_decode_augment_bytes():
