@@ -39,6 +39,46 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
+    # "softmax": top-k of softmax gates with a capacity per expert, and a
+    # Switch-style balance loss.  "sigmoid": select the top k by sigmoid
+    # score plus a correction bias, weight by the unbiased scores
+    # normalized over the k and times ``routed_scaling``, drop nothing,
+    # and take DeepSeek-V3's sequence-wise balance loss.
+    router: str = "softmax"
+    routed_scaling: float = 1.0
+    # the layer holds experts [0, n_held) (one chip's share of a larger
+    # expert-parallel layer); the router still scores all ``n_experts``.
+    # 0 holds every expert.  Under expert parallelism the ranks split the
+    # held experts.  Only the "sigmoid" router holds a share.
+    n_held: int = 0
+
+    def __post_init__(self):
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router {self.router!r}")
+        if self.n_held and (self.router != "sigmoid"
+                            or not 0 < self.n_held <= self.n_experts):
+            raise ValueError(
+                f"n_held {self.n_held} needs the sigmoid router and at most "
+                f"n_experts ({self.n_experts}) experts")
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2), full-sequence form: q
+    from ``x`` directly (no q LoRA); keys and values from a shared latent
+    of ``kv_lora_rank`` plus one rotary key shared by the heads."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -67,6 +107,9 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
+    # the first k layers have a dense MLP of width d_ff before the MoE ones
+    first_dense_layers: int = 0
     ssm: Optional[SSMConfig] = None
     # hybrid: a shared (weight-tied) attention block applied every k layers
     hybrid_attn_every: int = 0
@@ -77,6 +120,7 @@ class ModelConfig:
     # frontends for [audio]/[vlm]: stub supplies precomputed embeddings
     frontend: str = "none"                 # none | audio_stub | vision_stub
     frontend_tokens: int = 0               # prefix embedding count per sample
+    text_tokens: int = 0                   # [vlm] caption tokens after them
     # encoder-only classification head (vit)
     n_classes: int = 0
     source: str = ""                       # provenance tag from the brief
@@ -113,17 +157,30 @@ class ModelConfig:
             per_layer = d * (2 * d_in + 2 * s.d_state + n_h) + d_in * d + \
                 (d_in + 2 * s.d_state) * s.d_conv + d_in + 2 * n_h + d
             return emb + self.n_layers * per_layer
-        attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
-            + (self.n_heads * hd) * d
+        if self.mla is not None:
+            a = self.mla
+            H = self.n_heads
+            attn = d * H * a.qk_head_dim \
+                + d * (a.kv_lora_rank + a.qk_rope_head_dim) + a.kv_lora_rank \
+                + a.kv_lora_rank * H * (a.qk_nope_head_dim + a.v_head_dim) \
+                + H * a.v_head_dim * d
+        else:
+            attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
+                + (self.n_heads * hd) * d
         dense_ff = 3 * d * self.d_ff  # gated (silu) mlp
         norms = 2 * d
         if self.moe is not None:
             e = self.moe
-            ff = 3 * d * e.d_ff_expert * (e.n_experts + e.n_shared) + d * e.n_experts
+            ff = 3 * d * e.d_ff_expert * (e.held + e.n_shared) \
+                + d * e.n_experts
+            if e.router == "sigmoid":
+                ff += e.n_experts             # the selection bias
         else:
             ff = dense_ff
+        k = self.first_dense_layers
         per_layer = attn + ff + norms
-        n = emb + self.n_layers * per_layer + d
+        n = emb + (self.n_layers - k) * per_layer \
+            + k * (attn + dense_ff + norms) + d
         if self.family == "hybrid":
             # replace ff/attn estimate with mamba blocks + one shared attn block
             s = self.ssm
@@ -149,9 +206,10 @@ class ModelConfig:
         d = self.d_model
         e = self.moe
         full = self.n_params()
-        all_ff = 3 * d * e.d_ff_expert * (e.n_experts + e.n_shared)
+        all_ff = 3 * d * e.d_ff_expert * (e.held + e.n_shared)
         act_ff = 3 * d * e.d_ff_expert * (e.top_k + e.n_shared)
-        return full - self.n_layers * (all_ff - act_ff)
+        return full - (self.n_layers - self.first_dense_layers) * (
+            all_ff - act_ff)
 
 
 # ---------------------------------------------------------------------------
@@ -183,6 +241,8 @@ def shape_applicable(model: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]
     """(applicable, reason-if-not) for an (arch, shape) cell."""
     if shape.kind == "decode" and not model.has_decoder:
         return False, "encoder-only arch has no decode step"
+    if not shape.is_train and model.mla is not None:
+        return False, "latent attention has no decode cache"
     if shape.name == "long_500k" and not model.sub_quadratic:
         return False, "long_500k needs sub-quadratic attention (full-attention arch)"
     return True, ""

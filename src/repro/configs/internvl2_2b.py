@@ -2,7 +2,8 @@
 
 24L d_model=2048 16H (GQA kv=8) d_ff=8192 vocab=92553.  The InternViT
 frontend is a STUB: ``input_specs()`` supplies precomputed patch embeddings
-(256 visual tokens per image) that are prepended to the text sequence.
+(256 visual tokens per image) that are prepended to the text sequence;
+fed by Seneca, each image is followed by 768 caption tokens.
 """
 from repro.configs.base import ModelConfig
 
@@ -18,6 +19,7 @@ CONFIG = ModelConfig(
     head_dim=128,
     frontend="vision_stub",
     frontend_tokens=256,
+    text_tokens=768,
     rope_theta=1_000_000.0,
     source="arXiv:2404.16821; hf",
 )
@@ -27,4 +29,4 @@ def reduced() -> ModelConfig:
     import dataclasses
     return dataclasses.replace(
         CONFIG, n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
-        vocab_size=512, head_dim=16, frontend_tokens=8)
+        vocab_size=512, head_dim=16, frontend_tokens=8, text_tokens=24)

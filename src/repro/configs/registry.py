@@ -22,6 +22,7 @@ _MODULES: Dict[str, str] = {
     "deepseek-moe-16b": "repro.configs.deepseek_moe_16b",
     "kimi-k2-1t-a32b": "repro.configs.kimi_k2_1t_a32b",
     "internvl2-2b": "repro.configs.internvl2_2b",
+    "kimi-vl-a3b": "repro.configs.kimi_vl_a3b",
     "zamba2-1.2b": "repro.configs.zamba2_1_2b",
     "mamba2-1.3b": "repro.configs.mamba2_1_3b",
     "vit-huge": "repro.configs.vit_huge",
@@ -45,7 +46,7 @@ def get_reduced(arch_id: str) -> ModelConfig:
 
 
 def cells(arch_ids=None) -> List[Tuple[ModelConfig, ShapeConfig, bool, str]]:
-    """All (arch x shape) cells with applicability flags (40 for the 10)."""
+    """All (arch x shape) cells with applicability flags (44 for the 11)."""
     out = []
     for aid in (arch_ids or ASSIGNED_ARCHS):
         m = get(aid)

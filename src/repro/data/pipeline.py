@@ -131,7 +131,8 @@ class StageTimes:
     ``admit`` (encoded admissions).  Two counts go with them:
     ``assembles`` (launches of the program that assembles a batch) and
     ``row_slices`` (rows cut out of a group's output for admission).
-    ``patchify`` is the image feed's span (``launch/train.py``)."""
+    ``patchify`` and ``text`` are the image feed's spans
+    (``launch/train.py``)."""
     fetch: float = 0.0
     decode: float = 0.0
     augment: float = 0.0
@@ -145,6 +146,7 @@ class StageTimes:
     admit_rows: float = 0.0
     upkeep: float = 0.0
     patchify: float = 0.0
+    text: float = 0.0
     lookup: float = 0.0
     admit: float = 0.0
     assembles: int = 0

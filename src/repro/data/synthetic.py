@@ -14,6 +14,7 @@ interface, drop-in behind :class:`~repro.data.storage.RemoteStorage`.
 """
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass
 from typing import Dict, Tuple
@@ -46,6 +47,46 @@ def pixel_hash(base: int, n: int) -> np.ndarray:
     x *= np.uint32(_HASH_M2)
     x ^= x >> np.uint32(16)
     return (x & np.uint32(0xFF)).astype(np.uint8)
+
+
+# splitmix64 finalizer constants, for the caption token stream
+_MIX_GOLDEN = 0x9E3779B97F4A7C15
+_MIX_M1 = 0xBF58476D1CE4E5B9
+_MIX_M2 = 0x94D049BB133111EB
+
+
+def _mix64(z: np.ndarray) -> np.ndarray:
+    z = z + np.uint64(_MIX_GOLDEN)
+    z = (z ^ (z >> np.uint64(30))) * np.uint64(_MIX_M1)
+    z = (z ^ (z >> np.uint64(27))) * np.uint64(_MIX_M2)
+    return z ^ (z >> np.uint64(31))
+
+
+@functools.lru_cache(maxsize=8)
+def _zipf_cumulative(vocab: int) -> np.ndarray:
+    """Cumulative integer weights of Zipf(1) over ``vocab`` ranks: rank k
+    weighs floor(2**24 / (k + 1))."""
+    counts = (1 << 24) // np.arange(1, vocab + 1, dtype=np.int64)
+    cum = np.cumsum(counts)
+    cum.setflags(write=False)
+    return cum
+
+
+def caption_ids(seed: int, ids: np.ndarray, length: int,
+                vocab: int) -> np.ndarray:
+    """(len(ids), length) int32 caption token ids of the samples ``ids``:
+    each a pure function of (``seed``, sample id, position), drawn from
+    Zipf(1) over [0, vocab) by exact integer arithmetic (a hashed uint32
+    modulo the total weight, then the first rank whose cumulative weight
+    exceeds it), so id 0 is the commonest token."""
+    cum = _zipf_cumulative(int(vocab))
+    per_sample = _mix64(_mix64(np.full(1, seed & 0xFFFFFFFFFFFFFFFF,
+                                       np.uint64))
+                        ^ np.asarray(ids, np.int64).astype(np.uint64))
+    pos = np.arange(length, dtype=np.uint64)
+    r = (_mix64(per_sample[:, None] + pos[None, :]) >> np.uint64(32)
+         ).astype(np.int64) % cum[-1]
+    return np.searchsorted(cum, r, side="right").astype(np.int32)
 
 
 @dataclass(frozen=True)

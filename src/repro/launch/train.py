@@ -8,9 +8,12 @@ model zoo, the optimizer, and fault tolerance into one runnable loop:
 
 The smoke-scale config is the default so the driver runs on CPU;
 ``--no-reduced`` selects the full config.
-For the image model (--arch vit-huge) batches come from the real Seneca
-image pipeline; LM archs use the token pipeline (synthetic corpus).
-The image pipeline's device route is
+Image consumers are fed by the real Seneca image pipeline: the encoder
+(--arch vit-huge) gets patch embeddings and class labels, a VLM (--arch
+kimi-vl-a3b, internvl2-2b) the patch embeddings followed by the
+config's ``text_tokens`` caption tokens of each sample, with next-token
+labels on the text.  Text-only LM archs use the token pipeline
+(synthetic corpus).  The image pipeline's device route is
 
     PYTHONPATH=src python -m repro.launch.train --arch vit-huge \
         --no-reduced --remat full --batch 32 --executor device \
@@ -19,7 +22,13 @@ The image pipeline's device route is
 fused decode+augment kernel for cold samples, an HBM cache tier that
 serves warm samples with no host->device payload bytes, device collate
 and a device-side patchify stub, so a batch reaches the train step
-without a host round trip.
+without a host round trip.  A VLM trains on the same route:
+
+    PYTHONPATH=src python -m repro.launch.train --arch kimi-vl-a3b \
+        --executor device --samples 64 --batch 4 --steps 8 --ckpt-every 0
+
+(the whole of kimi-vl-a3b does not fit one chip; the benchmark's
+``bench/configs/kimi-vl-a3b.json`` trains one chip's share of it).
 """
 from __future__ import annotations
 
@@ -40,7 +49,7 @@ from repro.configs import registry
 from repro.configs.base import ModelConfig, ParallelismConfig
 from repro.data.pipeline import EXECUTORS, DSIPipeline
 from repro.data.storage import RemoteStorage
-from repro.data.synthetic import imagenet_like, tiny
+from repro.data.synthetic import caption_ids, imagenet_like, tiny
 from repro.distributed.ft import FTConfig, ResilientTrainer
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build
@@ -102,6 +111,12 @@ def image_batch_source(model, batch: int, seed: int = 0,
     eviction) — ODS's refcount eviction, whose threshold is the number
     of jobs, would drop each row on its first serve.
 
+    A ``vlm`` model is fed, beside the patch embeddings, the caption of
+    each sample (``text_tokens`` ids after the image tokens, a pure
+    function of the dataset's seed and the sample id:
+    :func:`repro.data.synthetic.caption_ids`) and next-token labels on
+    the text positions, -1 on the image ones.
+
     Returns (next_batch, pipeline, server); the server is the
     :class:`repro.api.SenecaServer` facade — open more sessions on it for
     concurrent jobs."""
@@ -128,7 +143,23 @@ def image_batch_source(model, batch: int, seed: int = 0,
                     "labels": jnp.asarray(raw["labels"] % n_classes,
                                           jnp.int32)}
 
-    return next_batch, pipe, server
+    if getattr(model.cfg, "family", None) != "vlm":
+        return next_batch, pipe, server
+    n_text, vocab = model.cfg.text_tokens, model.cfg.vocab_size
+
+    def next_vlm_batch():
+        raw = pipe.next_batch()
+        with pipe.times.span("patchify"):
+            embeds = patchify_stub(raw["images"], T, d)
+        with pipe.times.span("text"):
+            text = caption_ids(ds.seed, raw["ids"], n_text + 1, vocab)
+            labels = np.concatenate(
+                [np.full((len(text), T), -1, np.int32), text[:, 1:]], 1)
+            return {"patch_embeds": embeds,
+                    "tokens": jnp.asarray(text[:, :-1]),
+                    "labels": jnp.asarray(labels)}
+
+    return next_vlm_batch, pipe, server
 
 
 def parse_args(argv=None) -> argparse.Namespace:
@@ -144,7 +175,9 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "backward pass")
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=16)
-    ap.add_argument("--seq", type=int, default=128)
+    ap.add_argument("--seq", type=int, default=128,
+                    help="text-only LM sequence length (a VLM's comes "
+                         "from its config)")
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt-dir", default=None,
                     help="resume from / checkpoint to this directory "
@@ -153,7 +186,8 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--ckpt-every", type=int, default=50,
                     help="steps between checkpoints; 0 disables them")
     ap.add_argument("--microbatches", type=int, default=1)
-    image = ap.add_argument_group("image pipeline (--arch vit-huge)")
+    image = ap.add_argument_group(
+        "image pipeline (--arch vit-huge, kimi-vl-a3b, internvl2-2b)")
     image.add_argument("--executor", default="per-sample",
                        choices=EXECUTORS)
     image.add_argument("--dataset", default="tiny",
@@ -172,10 +206,10 @@ def model_config(args: argparse.Namespace) -> ModelConfig:
 def run(args: argparse.Namespace,
         consume_hook: Optional[Callable] = None) -> Dict:
     """Train ``args.steps`` steps; returns the history, the step's
-    compile seconds and memory analysis and, for image archs, the
-    pipeline's stage seconds, the server's final ``stats()`` and the
-    host->device payload bytes of each epoch.  ``consume_hook`` sees
-    every raw batch the image pipeline serves.
+    compile seconds and memory analysis and, for image consumers
+    (encoder and VLM archs), the pipeline's stage seconds, the server's
+    final ``stats()`` and the host->device payload bytes of each epoch.
+    ``consume_hook`` sees every raw batch the image pipeline serves.
 
     The step is compiled ahead of time on the first batch, with the
     parameters and optimizer state donated, so the compile time and the
@@ -194,7 +228,7 @@ def run(args: argparse.Namespace,
 
     out: Dict = {"cfg": cfg}
     pipe = server = None
-    if cfg.family == "encoder":
+    if cfg.family in ("encoder", "vlm"):
         ds = IMAGE_DATASETS[args.dataset](n=args.samples)
         source, pipe, server = image_batch_source(
             model, args.batch, dataset=ds, executor=args.executor,

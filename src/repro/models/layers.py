@@ -107,7 +107,7 @@ def _project_qkv(p: Dict, x: jax.Array, kv_x: jax.Array, cfg: ModelConfig,
 
 
 def _sdpa(q, k, v, mask, cfg: ModelConfig):
-    """Grouped scaled-dot-product attention. q:(B,Sq,H,hd) k/v:(B,Sk,K,hd).
+    """Grouped scaled-dot-product attention. q/k:(B,S,H|K,hd) v:(B,Sk,K,hd_v).
 
     Materializes (Sq, Sk) scores — use only when Sq*Sk is small (decode,
     short sequences).  Long sequences go through :func:`blockwise_attention`.
@@ -121,7 +121,7 @@ def _sdpa(q, k, v, mask, cfg: ModelConfig):
         scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v)
-    return out.reshape(B, Sq, H, hd)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
@@ -131,7 +131,8 @@ def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
     Never materializes more than one (B, K, G, q_block, Sk) score tile, so
     32k prefill compiles within HBM.  Online softmax is unnecessary because
     each scan step owns its complete score row.
-    q: (B,Sq,H,hd); k/v: (B,Sk,K,hd); q_offset = absolute position of q[0].
+    q: (B,Sq,H,hd); k: (B,Sk,K,hd); v: (B,Sk,K,hd_v); q_offset = absolute
+    position of q[0].
     """
     B, Sq, H, hd = q.shape
     Sk, K = k.shape[1], k.shape[2]
@@ -159,7 +160,7 @@ def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
         return None, out
 
     _, outs = jax.lax.scan(step, None, (qr, jnp.arange(nb)))
-    out = jnp.moveaxis(outs, 0, 1).reshape(B, Sq, H, hd)
+    out = jnp.moveaxis(outs, 0, 1).reshape(B, Sq, H, v.shape[-1])
     return out
 
 
@@ -200,6 +201,60 @@ def attention(p: Dict, x: jax.Array, cfg: ModelConfig, *,
     if return_kv:
         return y, k, v
     return y
+
+
+def mla_defs(cfg: ModelConfig) -> Dict:
+    d, H, a = cfg.d_model, cfg.n_heads, cfg.mla
+    r = a.kv_lora_rank
+    return {
+        "wq": ParamDef((d, H * a.qk_head_dim), ("embed", "q_heads")),
+        "wkv_a": ParamDef((d, r + a.qk_rope_head_dim), ("embed", None)),
+        "kv_norm": ParamDef((r,), (None,), init="ones"),
+        "wkv_b": ParamDef((r, H * (a.qk_nope_head_dim + a.v_head_dim)),
+                          (None, "q_heads")),
+        "wo": ParamDef((H * a.v_head_dim, d), ("q_heads", "embed"),
+                       scale=1.0 / max(1, (2 * cfg.n_layers)) ** 0.5),
+    }
+
+
+def mla_attention(p: Dict, x: jax.Array, cfg: ModelConfig, *,
+                  positions: jax.Array) -> jax.Array:
+    """Causal multi-head latent attention over the full sequence.
+
+    Per head, q is ``qk_nope_head_dim`` dims without position and
+    ``qk_rope_head_dim`` rotary dims.  ``x W_kv_a`` gives the latent
+    (RMS-normed, then ``W_kv_b`` maps it to each head's unrotated key and
+    its value) and one rotary key that every head shares.  Scores are
+    scaled by 1/sqrt(qk_head_dim); values are ``v_head_dim`` wide."""
+    with jax.named_scope("mla"):
+        B, S, _ = x.shape
+        a, H = cfg.mla, cfg.n_heads
+        nope, r = a.qk_nope_head_dim, a.kv_lora_rank
+        q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(
+            B, S, H, a.qk_head_dim)
+        q = jnp.concatenate(
+            [q[..., :nope], rotary(q[..., nope:], positions, cfg.rope_theta)],
+            axis=-1)
+        kv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
+        latent = rmsnorm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+        k_rope = rotary(kv[:, :, None, r:], positions, cfg.rope_theta)
+        kvb = jnp.einsum("bsr,rh->bsh", latent, p["wkv_b"]).reshape(
+            B, S, H, nope + a.v_head_dim)
+        k = jnp.concatenate(
+            [kvb[..., :nope],
+             jnp.broadcast_to(k_rope, (B, S, H, a.qk_rope_head_dim))],
+            axis=-1)
+        v = kvb[..., nope:]
+        q = shard(q, "batch", "act_seq", "act_heads", None)
+        k = shard(k, "batch", "act_seq", "act_heads", None)
+        v = shard(v, "batch", "act_seq", "act_heads", None)
+        if S * S <= _DIRECT_SDPA_LIMIT:
+            out = _sdpa(q, k, v, causal_mask(S, S), cfg)
+        else:
+            out = blockwise_attention(q, k, v, causal=True)
+        y = jnp.einsum("bsh,hd->bsd", out.reshape(B, S, H * a.v_head_dim),
+                       p["wo"])
+        return shard(y, "batch", "act_seq", "act_embed")
 
 
 def attention_decode(p: Dict, x: jax.Array, cfg: ModelConfig, *,

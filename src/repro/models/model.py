@@ -39,6 +39,10 @@ class Model:
     def loss(self, params, batch, *, remat: str = "none") -> jax.Array:
         return tfm.loss_fn(params, self.cfg, batch, remat=remat)
 
+    def loss_and_stats(self, params, batch, *, remat: str = "none"):
+        """(loss, step counters); see ``transformer.loss_and_stats``."""
+        return tfm.loss_and_stats(params, self.cfg, batch, remat=remat)
+
     def forward(self, params, batch, *, remat: str = "none"):
         return tfm.forward(params, self.cfg, batch, remat=remat)
 

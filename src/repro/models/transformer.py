@@ -27,17 +27,17 @@ F32 = jnp.float32
 # ---------------------------------------------------------------------------
 
 def _block_defs(cfg: ModelConfig, *, cross: bool = False,
-                ssm: bool = False) -> Dict:
+                ssm: bool = False, dense: bool = False) -> Dict:
     d = {"ln1": lyr.rmsnorm_def(cfg.d_model)}
     if ssm:
         d["ssm"] = ssm_mod.ssm_defs(cfg)
         return d
-    d["attn"] = lyr.attention_defs(cfg)
+    d["attn"] = lyr.mla_defs(cfg) if cfg.mla else lyr.attention_defs(cfg)
     if cross:
         d["lnc"] = lyr.rmsnorm_def(cfg.d_model)
         d["cross"] = lyr.attention_defs(cfg, cross=True)
     d["ln2"] = lyr.rmsnorm_def(cfg.d_model)
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense:
         d["moe"] = moe_mod.moe_defs(cfg)
     else:
         d["mlp"] = lyr.mlp_defs(cfg)
@@ -49,7 +49,10 @@ def param_defs(cfg: ModelConfig) -> Dict:
     defs: Dict = {"final_norm": lyr.rmsnorm_def(cfg.d_model)}
     if cfg.family in ("dense", "moe", "vlm"):
         defs["embed"] = lyr.embed_defs(cfg, v_pad)
-        defs["blocks"] = stack_defs(_block_defs(cfg), cfg.n_layers)
+        k = cfg.first_dense_layers
+        if k:
+            defs["dense"] = stack_defs(_block_defs(cfg, dense=True), k)
+        defs["blocks"] = stack_defs(_block_defs(cfg), cfg.n_layers - k)
     elif cfg.family == "ssm":
         defs["embed"] = lyr.embed_defs(cfg, v_pad)
         defs["blocks"] = stack_defs(_block_defs(cfg, ssm=True), cfg.n_layers)
@@ -83,8 +86,15 @@ def _attn_block(lp: Dict, x: jax.Array, cfg: ModelConfig, positions,
                 *, causal: bool, window: int = 0, enc_out=None,
                 use_rope: bool = True, return_kv: bool = False):
     h = lyr.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-    a = lyr.attention(lp["attn"], h, cfg, positions=positions, causal=causal,
-                      window=window, use_rope=use_rope, return_kv=return_kv)
+    if cfg.mla is not None:
+        if return_kv or not causal or window:
+            raise NotImplementedError(
+                "latent attention runs causal over the full sequence only")
+        a = lyr.mla_attention(lp["attn"], h, cfg, positions=positions)
+    else:
+        a = lyr.attention(lp["attn"], h, cfg, positions=positions,
+                          causal=causal, window=window, use_rope=use_rope,
+                          return_kv=return_kv)
     if return_kv:
         a, k, v = a
     x = x + a
@@ -121,15 +131,29 @@ def _ssm_block(lp: Dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     return x + ssm_mod.ssm_block(lp["ssm"], h, cfg)
 
 
-def _scan_blocks(blocks, x, body, remat: str):
+def _scan_blocks(blocks, x, body, remat: str, aux=None):
     if remat != "none":
         body = jax.checkpoint(body)
 
     def wrapped(carry, lp):
         return body(carry, lp), None
 
-    (x, aux), _ = jax.lax.scan(wrapped, (x, jnp.zeros((), F32)), blocks)
+    if aux is None:
+        aux = jnp.zeros((), F32)
+    (x, aux), _ = jax.lax.scan(wrapped, (x, aux), blocks)
     return x, aux
+
+
+def _aux_zero(stack: Dict):
+    """The sum the decoder carries over ``stack``'s layers: each MoE
+    layer's ``{"loss", "held", "load"}``, else a zero loss."""
+    if "moe" in stack:
+        return {k: jnp.zeros((), F32) for k in ("loss", "held", "load")}
+    return jnp.zeros((), F32)
+
+
+def _add_aux(a, b):
+    return jax.tree.map(jnp.add, a, b)
 
 
 def run_decoder(params, x, cfg: ModelConfig, positions, *,
@@ -170,9 +194,12 @@ def run_decoder(params, x, cfg: ModelConfig, positions, *,
         h, a = _attn_block(lp, h, cfg, positions, causal=causal,
                            window=window, enc_out=enc_out,
                            use_rope=use_rope)
-        return (h, aux + a)
+        return (h, _add_aux(aux, a))
 
-    return _scan_blocks(params["blocks"], x, body, remat)
+    if "dense" in params:
+        x, _ = _scan_blocks(params["dense"], x, body, remat)
+    return _scan_blocks(params["blocks"], x, body, remat,
+                        _aux_zero(params["blocks"]))
 
 
 def run_encoder(params, src: jax.Array, cfg: ModelConfig,
@@ -255,12 +282,27 @@ def cross_entropy(logits: jax.Array, labels: jax.Array,
 
 def loss_fn(params, cfg: ModelConfig, batch: Dict, *,
             remat: str = "none") -> jax.Array:
+    return loss_and_stats(params, cfg, batch, remat=remat)[0]
+
+
+def loss_and_stats(params, cfg: ModelConfig, batch: Dict, *,
+                   remat: str = "none") -> Tuple[jax.Array, Dict]:
+    """(loss, step counters).  MoE configs count, as means over their MoE
+    layers, ``moe_held_share`` (the share of the assignments that land
+    on the experts a layer holds) and ``moe_load_max_over_mean`` (the
+    busiest held expert's assignments over the held experts' mean);
+    other configs count nothing."""
     logits, aux = forward(params, cfg, batch, remat=remat)
     if cfg.family == "encoder":
         lbl = batch["labels"]
         ce = cross_entropy(logits[:, None, :], lbl[:, None], cfg.n_classes)
-        return ce + aux
-    return cross_entropy(logits, batch["labels"], cfg.vocab_size) + aux
+        return ce + aux, {}
+    ce = cross_entropy(logits, batch["labels"], cfg.vocab_size)
+    if cfg.moe is None:
+        return ce + aux, {}
+    n_moe = cfg.n_layers - cfg.first_dense_layers
+    return ce + aux["loss"], {"moe_held_share": aux["held"] / n_moe,
+                              "moe_load_max_over_mean": aux["load"] / n_moe}
 
 
 # ---------------------------------------------------------------------------
@@ -270,6 +312,8 @@ def loss_fn(params, cfg: ModelConfig, batch: Dict, *,
 def cache_defs(cfg: ModelConfig, B: int, s_max: int) -> Dict:
     """Decode-state ParamDefs (init=zeros; reuses the ParamDef machinery
     so abstract shapes and PartitionSpecs come for free)."""
+    if cfg.mla is not None:
+        raise NotImplementedError("no decode cache for latent attention")
     hd = cfg.resolved_head_dim
     K = cfg.n_kv_heads
     L = cfg.n_layers
@@ -489,7 +533,7 @@ def prefill(params, cfg: ModelConfig, batch: Dict, cache: Dict,
         x, aux = carry
         x, a, k, v = _attn_block(lp, x, cfg, positions, causal=True,
                                  enc_out=enc_out, return_kv=True)
-        aux = aux + a
+        aux = _add_aux(aux, a)
         outs = (k, v)
         if cross:
             h = enc_out
@@ -503,7 +547,7 @@ def prefill(params, cfg: ModelConfig, batch: Dict, cache: Dict,
 
     if remat != "none":
         body = jax.checkpoint(body)
-    (x, _), outs = jax.lax.scan(body, (x, jnp.zeros((), F32)),
+    (x, _), outs = jax.lax.scan(body, (x, _aux_zero(params["blocks"])),
                                 params["blocks"])
     x = lyr.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = lyr.logits(params["embed"], x)

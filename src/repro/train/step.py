@@ -33,31 +33,34 @@ def build_train_step(model: Model, parallel: ParallelismConfig,
         # value_and_grad as a vjp, so that the profiler's op names tell
         # the forward pass from the backward one
         with jax.named_scope("forward"):
-            loss, pullback = jax.vjp(
-                lambda p: model.loss(p, mb, remat=remat), params)
+            loss, pullback, stats = jax.vjp(
+                lambda p: model.loss_and_stats(p, mb, remat=remat), params,
+                has_aux=True)
         with jax.named_scope("backward"):
             (grads,) = pullback(jnp.ones_like(loss))
-        return loss, grads
+        return loss, grads, stats
 
     def step(params, opt_state: AdamWState, batch):
         if n_micro > 1:
             mbs = _split_microbatches(batch, n_micro)
 
             def acc(carry, mb):
-                loss, g = loss_and_grads(params, mb)
-                return jax.tree.map(jnp.add, carry, g), loss
+                loss, g, stats = loss_and_grads(params, mb)
+                return jax.tree.map(jnp.add, carry, g), (loss, stats)
 
             zero = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            grads, losses = jax.lax.scan(acc, zero, mbs)
+            grads, (losses, stats) = jax.lax.scan(acc, zero, mbs)
             grads = jax.tree.map(lambda g: g / n_micro, grads)
             loss = jnp.mean(losses)
+            stats = jax.tree.map(jnp.mean, stats)
         else:
-            loss, grads = loss_and_grads(params, batch)
+            loss, grads, stats = loss_and_grads(params, batch)
         with jax.named_scope("optimizer"):
             new_params, new_state, gnorm = opt.update(grads, opt_state,
                                                       params)
-        metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gnorm}
+        metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gnorm,
+                   **stats}
         return new_params, new_state, metrics
 
     return step

@@ -187,6 +187,64 @@ def test_moe_ep_matches_local_dispatch():
     """)
 
 
+@pytest.mark.parametrize("arch, n_held", [
+    ("kimi-vl-a3b", 0), ("kimi-vl-a3b", 4), ("deepseek-moe-16b", 0)])
+def test_moe_ep_train_matches_one_device(arch, n_held):
+    """A MoE arch laid out as the program lays it out (experts over
+    'model'): loss, step counters and every gradient equal one device's.
+    The softmax router runs drop-free and without its balance loss,
+    whose mean over batch shards is not the whole batch's."""
+    out = run_sub(f"""
+        import dataclasses
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import Mesh
+        from repro.configs import registry
+        from repro.configs.base import TRAIN_4K
+        from repro.distributed.sharding import make_rules, use_rules
+        from repro.models.model import build, make_batch
+
+        cfg = registry.get_reduced('{arch}')
+        if cfg.moe.router == 'sigmoid':
+            moe = dataclasses.replace(cfg.moe, n_held={n_held})
+        else:
+            moe = dataclasses.replace(cfg.moe, capacity_factor=100.0,
+                                      aux_loss_weight=0.0)
+        cfg = dataclasses.replace(cfg, moe=moe)
+        m = build(cfg)
+        params = m.init(jax.random.key(0), dtype=jnp.float32)
+        batch = make_batch(jax.random.key(1), m, TRAIN_4K,
+                           reduced_shape=(4, 32))
+
+        def run(p, b):
+            (loss, stats), g = jax.value_and_grad(
+                lambda p: m.loss_and_stats(p, b), has_aux=True)(p)
+            return loss, stats, g
+
+        with jax.default_matmul_precision('highest'):
+            ref = jax.jit(run)(params, batch)
+            devs = np.asarray(jax.devices()[:8]).reshape(2, 4)
+            mesh = Mesh(devs, ('data', 'model'))
+            par = registry.default_parallelism(cfg, TRAIN_4K)
+            assert par.ep
+            rules = make_rules(cfg, TRAIN_4K, par, tp_size=4, dp_size=2,
+                               mesh=mesh)
+            with use_rules(rules), jax.set_mesh(mesh):
+                got = jax.jit(run)(params, batch)
+        held = float(ref[1]['moe_held_share'])
+        assert (held == 1.0) if cfg.moe.held == cfg.moe.n_experts \
+            else (0.0 < held < 1.0), held
+        for k in ref[1]:
+            assert abs(float(got[1][k]) - float(ref[1][k])) < 1e-5, k
+        assert abs(float(got[0]) - float(ref[0])) < 1e-5
+        gap = max(float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-12))
+                  for a, b in zip(jax.tree.leaves(got[2]),
+                                  jax.tree.leaves(ref[2])))
+        print('moe ep grad gap', gap)
+        assert gap < 1e-4, gap
+    """)
+    assert "moe ep grad gap" in out
+
+
 def test_seq_parallel_ssd_matches_local():
     """Sequence-parallel SSD (models/ssm_sp.py): sharding S over 'model'
     with cross-rank state hand-off must reproduce the local block exactly
