@@ -39,6 +39,11 @@ Three executors (the ``executor=`` knob):
   decode/augment, so throughput approaches the slowest *stage* instead
   of the per-batch sum (benchmarks/fig_pipeline_throughput.py).
 
+Under any executor, ``start_prefetch()`` builds batches ahead of the
+caller on a thread of the pipeline's own and ``get()`` takes them, in
+the order ``next_batch`` would have given them; the stage-parallel
+executor is its own prefetcher.
+
 Both executors produce identical tensors for a given (epoch, sample id):
 augmentation parameters derive from per-sample seeds, not executor
 scheduling.  Batches carry an additive ``"ids"`` key with the sample ids
@@ -132,7 +137,13 @@ class StageTimes:
     ``assembles`` (launches of the program that assembles a batch) and
     ``row_slices`` (rows cut out of a group's output for admission).
     ``patchify`` and ``text`` are the image feed's spans
-    (``launch/train.py``)."""
+    (``launch/train.py``).  :meth:`DSIPipeline.get` times ``wait`` (the
+    caller's wait for a built batch) and counts ``taken`` (batches
+    handed out) and ``ready`` (those already built when asked for).
+
+    Each field has one writer: under :meth:`DSIPipeline.start_prefetch`
+    the route's fields are written by the prefetch thread, and ``wait``,
+    ``taken``, ``ready``, ``patchify`` and ``text`` by the caller."""
     fetch: float = 0.0
     decode: float = 0.0
     augment: float = 0.0
@@ -147,10 +158,13 @@ class StageTimes:
     upkeep: float = 0.0
     patchify: float = 0.0
     text: float = 0.0
+    wait: float = 0.0
     lookup: float = 0.0
     admit: float = 0.0
     assembles: int = 0
     row_slices: int = 0
+    taken: int = 0
+    ready: int = 0
     now: Callable[[], float] = field(default=time.monotonic, repr=False,
                                      compare=False)
 
@@ -768,8 +782,8 @@ class DSIPipeline:
         else:
             from repro.api.backends import resolve_augment_backend
             self.augment = resolve_augment_backend(augment_backend)
-        # consumer-rate hook: called with every batch ``next_batch``
-        # emits, on the emitting thread, before the batch is returned.
+        # consumer-rate hook: called with every batch ``next_batch`` or
+        # ``get`` hands out, on the caller's thread, before it returns.
         # The WorkloadRunner installs a rate limiter here to emulate GPU
         # ingest (repro/workload/runner.py); anything callable works.
         self._consume_hook = consume_hook
@@ -877,18 +891,27 @@ class DSIPipeline:
 
     # ------------------------------------------------------------------
     def next_batch(self) -> Dict[str, np.ndarray]:
+        """The next batch, built on the calling thread, then given to
+        the consume hook.  Once :meth:`start_prefetch` has started its
+        thread, that thread alone calls this, and :meth:`get` gives the
+        hook each batch as it hands it out."""
+        producer = self._thread is not None
+        if producer and threading.current_thread() is not self._thread:
+            raise RuntimeError("the prefetch thread runs this pipeline's "
+                               "route; take its batches with get()")
         if self.executor == "stage-parallel":
             # block until produced, like the per-sample path: slowness is
             # backpressure, not failure (errors still raise immediately)
             batch = self._ensure_executor().get_batch(timeout=None)
-            if self._consume_hook is not None:
-                self._consume_hook(batch)
-            return batch
-        if self.executor == "device":
+        elif self.executor == "device":
             batch = self._next_batch_device()
-            if self._consume_hook is not None:
-                self._consume_hook(batch)
-            return batch
+        else:
+            batch = self._next_batch_host()
+        if self._consume_hook is not None and not producer:
+            self._consume_hook(batch)
+        return batch
+
+    def _next_batch_host(self) -> Dict[str, np.ndarray]:
         ids, _forms = self.session.next_batch_ids()
         epoch_tag = self.session.epoch
         imgs = list(self.pool.map(
@@ -908,8 +931,6 @@ class DSIPipeline:
         # adaptive-repartition tick: a fast no-op in "static"/"on-change"
         # modes; in "adaptive" this is where calibrated drift is checked
         self.svc.maybe_repartition()
-        if self._consume_hook is not None:
-            self._consume_hook(batch)
         return batch
 
     def _next_batch_device(self) -> Dict[str, np.ndarray]:
@@ -1163,10 +1184,17 @@ class DSIPipeline:
             return self._executor
 
     def start_prefetch(self) -> None:
+        """Build batches ahead of the caller, up to ``prefetch`` queued
+        and one more held, on a thread of the pipeline's own; take them
+        with :meth:`get`.  A second call finds the thread running and
+        does nothing.  From then on only that thread runs the route:
+        :meth:`next_batch` from any other thread is an error."""
         if self.executor == "stage-parallel":
             # the stage executor IS the prefetcher: out_q holds up to
             # ``prefetch`` collated batches
             self._ensure_executor()
+            return
+        if self._thread is not None:
             return
 
         def run():
@@ -1190,20 +1218,38 @@ class DSIPipeline:
                     # dropping this one's sample ids and wasting the work)
                     continue
                 batch = None
-        self._thread = threading.Thread(target=run, daemon=True)
+        self._thread = threading.Thread(target=run, daemon=True,
+                                        name="seneca-prefetch")
         self._thread.start()
 
-    def get(self, timeout: float = 60.0) -> Dict[str, np.ndarray]:
-        if self.executor == "stage-parallel":
-            batch = self._ensure_executor().get_batch(timeout)
-            # same contract as next_batch(): the hook fires once per
-            # emitted batch.  (On the per-sample path below, batches
-            # reach the queue via the prefetch thread's next_batch(),
-            # which already fired it.)
-            if self._consume_hook is not None:
-                self._consume_hook(batch)
-            return batch
-        deadline = time.monotonic() + timeout
+    def get(self, timeout: Optional[float] = 60.0
+            ) -> Dict[str, np.ndarray]:
+        """The next batch :meth:`start_prefetch` built, in the order
+        :meth:`next_batch` would have given them.  ``timeout=None``
+        waits as long as the prefetch thread lives (a slow build is
+        backpressure, as in ``next_batch``); a finite timeout raises
+        ``queue.Empty`` at the deadline.  A prefetch thread that failed
+        re-raises its exception here, chained.  The wait is the span
+        ``wait``; ``taken`` counts the batches handed out and ``ready``
+        those already queued when asked for.  The consume hook fires
+        here, on the caller's thread, with each batch handed out."""
+        with self.times.span("wait"):
+            if self.executor == "stage-parallel":
+                executor = self._ensure_executor()
+                ready = not executor.out_q.empty()
+                batch = executor.get_batch(timeout)
+            else:
+                ready = not self._q.empty()
+                batch = self._take(timeout)
+        self.times.taken += 1
+        self.times.ready += ready
+        if self._consume_hook is not None:
+            self._consume_hook(batch)
+        return batch
+
+    def _take(self, timeout: Optional[float]) -> Dict[str, np.ndarray]:
+        deadline = float("inf") if timeout is None \
+            else time.monotonic() + timeout
         while True:
             # cap the poll at the remaining deadline (sub-poll timeouts
             # must not overshoot by a whole 0.2 s quantum)
@@ -1215,6 +1261,11 @@ class DSIPipeline:
                     raise RuntimeError(
                         "prefetch thread died; no more batches are "
                         "coming") from self._prefetch_exc
+                if timeout is None and (self._thread is None
+                                        or not self._thread.is_alive()):
+                    raise RuntimeError(
+                        "no prefetch thread is running: call "
+                        "start_prefetch() first, and not after stop()")
                 if time.monotonic() >= deadline:
                     raise
 
@@ -1227,7 +1278,9 @@ class DSIPipeline:
             self.telemetry.remove_concurrency(self._n_workers)
         self._stop.set()
         if self._thread:
-            self._thread.join(timeout=2.0)
+            # it ends after the batch in hand: join before the session
+            # closes under it
+            self._thread.join()
         if self._executor is not None:
             self._executor.stop()
         self.pool.shutdown(wait=False)

@@ -22,7 +22,9 @@ labels on the text.  Text-only LM archs use the token pipeline
 fused decode+augment kernel for cold samples, an HBM cache tier that
 serves warm samples with no host->device payload bytes, device collate
 and a device-side patchify stub, so a batch reaches the train step
-without a host round trip.  A VLM trains on the same route:
+without a host round trip.  The feed builds the next batch on the
+pipeline's prefetch thread while the step runs, and takes it with
+``DSIPipeline.get(timeout=None)``.  A VLM trains on the same route:
 
     PYTHONPATH=src python -m repro.launch.train --arch kimi-vl-a3b \
         --executor device --samples 64 --batch 4 --steps 8 --ckpt-every 0
@@ -117,6 +119,20 @@ def image_batch_source(model, batch: int, seed: int = 0,
     :func:`repro.data.synthetic.caption_ids`) and next-token labels on
     the text positions, -1 on the image ones.
 
+    The feed builds one batch ahead of its caller: its first call starts
+    the pipeline's prefetch thread (``DSIPipeline.start_prefetch``),
+    which runs the route for batch n+1 while the caller's step n runs,
+    and each call takes the next built batch with
+    ``pipe.get(timeout=None)``, which waits as long as that thread lives
+    and re-raises its error.  The batches are those serial
+    ``pipe.next_batch()`` calls give, in the same order; calls made
+    before the feed's first one run on the caller and continue the same
+    sequence.  ``patchify_stub``, the captions and ``consume_hook`` run
+    on the caller, as each batch is handed out.  A
+    ``Session.checkpoint_state()`` taken while the feed runs records the
+    sampler up to three batches (two queued, one held) past the last
+    batch the caller took.
+
     Returns (next_batch, pipeline, server); the server is the
     :class:`repro.api.SenecaServer` facade — open more sessions on it for
     concurrent jobs."""
@@ -136,8 +152,15 @@ def image_batch_source(model, batch: int, seed: int = 0,
     T, d = model.cfg.frontend_tokens, model.cfg.d_model
     n_classes = max(model.cfg.n_classes, 1)
 
+    def take():
+        # the first call starts the prefetch thread (later ones find it
+        # running), so calls of pipe.next_batch() made before it, as a
+        # tier fill's, run on the caller and continue the same ids
+        pipe.start_prefetch()
+        return pipe.get(timeout=None)
+
     def next_batch():
-        raw = pipe.next_batch()
+        raw = take()
         with pipe.times.span("patchify"):
             return {"patch_embeds": patchify_stub(raw["images"], T, d),
                     "labels": jnp.asarray(raw["labels"] % n_classes,
@@ -148,7 +171,7 @@ def image_batch_source(model, batch: int, seed: int = 0,
     n_text, vocab = model.cfg.text_tokens, model.cfg.vocab_size
 
     def next_vlm_batch():
-        raw = pipe.next_batch()
+        raw = take()
         with pipe.times.span("patchify"):
             embeds = patchify_stub(raw["images"], T, d)
         with pipe.times.span("text"):
@@ -293,9 +316,11 @@ def _chain(first, source):
 
 def _per_epoch_h2d(source, telemetry, n_samples: int, batch: int,
                    record: list):
-    """Wrap ``source`` to add each batch's host->device payload bytes
-    (the pipeline's "h2d" telemetry channel) to its epoch's entry of
-    ``record``."""
+    """Wrap ``source`` to add the host->device payload bytes (the
+    pipeline's "h2d" telemetry channel) recorded since its last batch to
+    the epoch of the batch it hands out, in ``record``.  The feed builds
+    up to three batches ahead, so the bytes of those built past an
+    epoch's end count in that epoch."""
     mark = [0, telemetry.channel_total_bytes("h2d")]  # samples, h2d seen
 
     def next_batch():

@@ -75,8 +75,8 @@ def test_as_dict_keeps_its_old_keys_and_adds_seconds():
     assert set(OLD_KEYS) <= set(d)
     assert "now" not in d
     assert set(CHILDREN) | {"next_batch", "patchify", "lookup",
-                            "admit"} <= set(d)
-    counts = ("batches", "assembles", "row_slices")
+                            "admit", "wait"} <= set(d)
+    counts = ("batches", "assembles", "row_slices", "taken", "ready")
     assert all(isinstance(d[k], int) for k in counts)
     assert all(isinstance(v, float) for k, v in d.items() if k not in counts)
 
