@@ -23,16 +23,14 @@ def main() -> None:
                             fig14_concurrency, fig15_ect, fig_concurrency,
                             fig_device_pipeline, fig_dynamic_jobs,
                             fig_fault_recovery, fig_live_makespan,
-                            fig_open_loop, fig_pipeline_throughput,
-                            fig_sharded, fig_tiered_cache, roofline_report,
-                            table6_mdp)
+                            fig_open_loop, fig_sharded, fig_tiered_cache,
+                            roofline_report, table6_mdp)
     modules = [
         ("fig3", fig3_cache_forms), ("fig4", fig4_pagecache),
         ("table6", table6_mdp), ("fig8", fig8_validation),
         ("fig10", fig10_makespan), ("fig13", fig13_hitrate),
         ("fig14", fig14_concurrency), ("fig15", fig15_ect),
         ("dynamic", fig_dynamic_jobs),
-        ("pipeline", fig_pipeline_throughput),
         ("device", fig_device_pipeline),
         ("live", fig_live_makespan),
         ("tiered", fig_tiered_cache),

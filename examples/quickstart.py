@@ -111,14 +111,12 @@ def run_seneca(args) -> None:
     ds = _make_dataset(args)
     server = SenecaServer.for_dataset(ds, cache_frac=0.35, seed=0,
                                       backend=args.backend,
-                                      augment_backend=args.augment_backend,
                                       repartition=args.repartition,
                                       **_spill_kwargs(args, ds),
                                       **_device_kwargs(args),
                                       **_shard_kwargs(args))
     print(f"[quickstart] MDP partition: {server.partition.label} "
           f"(backend={args.backend}, executor={args.executor}, "
-          f"augment={args.augment_backend}, "
           f"repartition={args.repartition}, shards={args.shards})")
     if server.service.disk_partition is not None:
         print(f"[quickstart] spill tier: disk split "
@@ -209,7 +207,6 @@ def run_multi(args) -> None:
     ds = _make_dataset(args)
     server = SenecaServer.for_dataset(ds, cache_frac=0.35, seed=0,
                                       backend=args.backend,
-                                      augment_backend=args.augment_backend,
                                       repartition=args.repartition,
                                       **_spill_kwargs(args, ds),
                                       **_device_kwargs(args),
@@ -345,15 +342,11 @@ def main() -> None:
     ap.add_argument("--backend", default="numpy",
                     choices=("numpy", "jax"))
     ap.add_argument("--executor", default="per-sample",
-                    choices=("per-sample", "stage-parallel", "device"),
-                    help="DSI pipeline executor (stage-parallel = async "
-                         "queue-fed stages; device = fused Pallas "
+                    choices=("per-sample", "device"),
+                    help="DSI pipeline route (per-sample = host "
+                         "fetch/decode/augment; device = fused Pallas "
                          "decode+augment with device collate, "
                          "docs/API.md)")
-    ap.add_argument("--augment-backend", default="numpy",
-                    choices=("numpy", "pallas"),
-                    help="batched augment engine for the stage-parallel "
-                         "executor (pallas = fused kernel)")
     ap.add_argument("--repartition", default="static",
                     choices=("static", "on-change", "adaptive"),
                     help="live cache repartitioning mode (docs/API.md)")

@@ -24,12 +24,9 @@ The fluid-flow simulator behind the paper-figure benchmarks is re-exported
 here too, so benchmark and example code imports one namespace only.  See
 docs/API.md for the full tour.
 """
-from repro.api.backends import (AugmentBackend, JaxOdsBackend,
-                                NumpyAugmentBackend, NumpyOdsBackend,
-                                OdsBackend, PallasAugmentBackend,
-                                augment_backend_names, backend_names,
-                                register_augment_backend, register_backend,
-                                resolve_augment_backend, resolve_backend)
+from repro.api.backends import (JaxOdsBackend, NumpyOdsBackend, OdsBackend,
+                                backend_names, register_backend,
+                                resolve_backend)
 from repro.api.policies import (AdmissionPolicy, CapacityAdmission,
                                 EvictionPolicy, LruEviction, NaiveSampler,
                                 OdsSampler, RefcountEviction, SamplerPolicy,
@@ -101,9 +98,6 @@ __all__ = [
     # backends
     "OdsBackend", "NumpyOdsBackend", "JaxOdsBackend",
     "register_backend", "resolve_backend", "backend_names",
-    "AugmentBackend", "NumpyAugmentBackend", "PallasAugmentBackend",
-    "register_augment_backend", "resolve_augment_backend",
-    "augment_backend_names",
     # profiles + closed-form model
     "HardwareProfile", "DatasetProfile", "JobProfile", "dsi_throughput",
     "dsi_throughput_tiered",

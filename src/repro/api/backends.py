@@ -31,10 +31,7 @@ from repro.core.ods import AUGMENTED, IN_STORAGE, ODSState
 
 __all__ = ["OdsBackend", "NumpyOdsBackend", "JaxOdsBackend",
            "NO_REFCOUNT_EVICT",
-           "register_backend", "resolve_backend", "backend_names",
-           "AugmentBackend", "NumpyAugmentBackend", "PallasAugmentBackend",
-           "register_augment_backend", "resolve_augment_backend",
-           "augment_backend_names"]
+           "register_backend", "resolve_backend", "backend_names"]
 
 
 @runtime_checkable
@@ -359,105 +356,6 @@ class JaxOdsBackend:
 
     def metadata_bytes(self):
         return self.n_samples * len(self.seen) // 8 + self.n_samples
-
-
-# ----------------------------------------------------------------------
-# augment backends: the batched-transform twin of the ODS backend knob.
-# The stage-parallel DSIPipeline executor hands its augment stage a whole
-# group of decoded samples at once; which engine runs the pixel math is
-# selected here (SenecaConfig.augment_backend / SenecaServer kwarg).
-@runtime_checkable
-class AugmentBackend(Protocol):
-    """Vectorized augmentation over a batch of decoded uint8 images.
-
-    ``augment_batch(images, crop_hw, seeds)`` takes (B,H,W,3) uint8 and
-    per-sample integer seeds and returns (B,ch,cw,3) float32.  Both
-    implementations derive the crop/flip parameters from the same
-    per-seed draw sequence (repro.data.augment.crop_flip_params), so the
-    transform is deterministic per *sample id*, not per batch
-    composition — swapping backends changes throughput, not content
-    (within float tolerance).
-    """
-
-    name: str
-
-    def augment_batch(self, images: np.ndarray, crop_hw: Tuple[int, int],
-                      seeds: np.ndarray) -> np.ndarray: ...
-
-
-class NumpyAugmentBackend:
-    """Host-CPU fallback: the per-sample augment_np loop (paper-faithful
-    placement; no jax required)."""
-
-    name = "numpy"
-
-    def augment_batch(self, images, crop_hw, seeds):
-        from repro.data.augment import augment_batch_np
-        return augment_batch_np(images, crop_hw, seeds)
-
-
-class PallasAugmentBackend:
-    """Fused Pallas crop+flip+normalize kernel (repro.kernels.augment):
-    interpret mode off-TPU, compiled Mosaic on TPU.  Parameters are
-    derived on host from the same per-sample seeds as the NumPy path."""
-
-    name = "pallas"
-
-    def __init__(self, interpret: Optional[bool] = None):
-        import jax  # baked into the toolchain; fail loud if absent
-        import jax.numpy as jnp
-        from repro.kernels.augment.ops import augment_batch_seeded
-        self._jnp = jnp
-        self._augment = augment_batch_seeded
-        self._interpret = interpret
-        self._size_counts: Dict[int, int] = {}
-        del jax
-
-    def augment_batch(self, images, crop_hw, seeds):
-        images = np.asarray(images)
-        # recurring group sizes (typically the full batch) earn an
-        # exact-size kernel trace — padding a 12-sample batch to 16
-        # forever would waste 33% augment work; one-off ragged sizes
-        # still share the power-of-two buckets
-        B = len(images)
-        self._size_counts[B] = self._size_counts.get(B, 0) + 1
-        bucket = B if self._size_counts[B] >= 2 else None
-        out = self._augment(images, np.asarray(seeds),
-                            crop_hw[0], crop_hw[1],
-                            out_dtype=self._jnp.float32,
-                            interpret=self._interpret, bucket=bucket)
-        return np.asarray(out, np.float32)
-
-
-_AUGMENT_BACKENDS: Dict[str, type] = {
-    "numpy": NumpyAugmentBackend,
-    "pallas": PallasAugmentBackend,
-    # alias: the ODS knob calls its jittable engine "jax"; accept the
-    # same spelling here
-    "jax": PallasAugmentBackend,
-}
-
-
-def register_augment_backend(name: str, factory: type) -> None:
-    _AUGMENT_BACKENDS[name] = factory
-
-
-def augment_backend_names() -> Tuple[str, ...]:
-    return tuple(sorted(_AUGMENT_BACKENDS))
-
-
-def resolve_augment_backend(spec):
-    """Name or instance -> AugmentBackend."""
-    if isinstance(spec, str):
-        try:
-            return _AUGMENT_BACKENDS[spec]()
-        except KeyError:
-            raise ValueError(
-                f"unknown augment backend {spec!r}; registered: "
-                f"{augment_backend_names()}") from None
-    if not isinstance(spec, AugmentBackend):
-        raise TypeError(f"{spec!r} does not implement AugmentBackend")
-    return spec
 
 
 _BACKENDS: Dict[str, type] = {"numpy": NumpyOdsBackend, "jax": JaxOdsBackend}

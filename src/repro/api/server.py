@@ -38,8 +38,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from repro.api.backends import (NO_REFCOUNT_EVICT, resolve_augment_backend,
-                                resolve_backend)
+from repro.api.backends import NO_REFCOUNT_EVICT, resolve_backend
 from repro.api.policies import resolve_policy
 from repro.api.telemetry import TelemetryAggregator
 from repro.cache.coalesce import ProductionTable
@@ -123,10 +122,6 @@ class SenecaConfig:
     split: Optional[Tuple[float, float, float]] = None
     # facade knobs: ODS metadata engine + policies by registered name
     backend: str = "numpy"
-    # batched augmentation engine for the stage-parallel pipeline executor
-    # ("numpy" loop fallback | "pallas"/"jax" fused kernel); the
-    # per-sample executor keeps its inline augment_np path either way
-    augment_backend: str = "numpy"
     sampler: Optional[str] = None      # None -> "ods" / "naive" per use_ods
     admission: Optional[str] = None    # None -> "unseen-only" / "capacity"
     eviction: Optional[str] = None     # None -> "refcount"
@@ -400,7 +395,7 @@ class SenecaService:
     """
 
     def __init__(self, cfg: SenecaConfig, *, backend=None, sampler=None,
-                 admission=None, eviction=None, augment_backend=None):
+                 admission=None, eviction=None):
         self.cfg = cfg
         if cfg.repartition not in REPARTITION_MODES:
             raise ValueError(f"unknown repartition mode "
@@ -518,8 +513,6 @@ class SenecaService:
             self.backend = resolve_backend(backend or cfg.backend,
                                            cfg.dataset.n_total,
                                            seed=cfg.seed)
-            self.augment = resolve_augment_backend(
-                augment_backend or cfg.augment_backend)
             self.rng = np.random.default_rng(cfg.seed + 1)
             self._residency_version = -1     # force the first push
             self._samplers: Dict[int, EpochSampler] = {}
@@ -954,7 +947,6 @@ class SenecaService:
             "partition": self.partition.label,
             "predicted_throughput": self.partition.throughput,
             "backend": self.backend.name,
-            "augment_backend": self.augment.name,
             "refill_errors": self.telemetry.error_count("refill"),
             "policies": {"sampler": self.sampler.name,
                          "admission": self.admission.name,
@@ -1130,15 +1122,13 @@ class SenecaServer:
 
     def __init__(self, cfg: SenecaConfig = None, *, backend=None,
                  sampler=None, admission=None, eviction=None,
-                 augment_backend=None,
                  service: Optional[SenecaService] = None):
         if service is None:
             if cfg is None:
                 raise ValueError("SenecaServer needs a SenecaConfig "
                                  "(or an existing service=)")
             service = SenecaService(cfg, backend=backend, sampler=sampler,
-                                    admission=admission, eviction=eviction,
-                                    augment_backend=augment_backend)
+                                    admission=admission, eviction=eviction)
         self.service = service
         self._ids = itertools.count()
         self._sessions: Dict[int, Session] = {}

@@ -12,9 +12,6 @@ samples.  :class:`TelemetryAggregator` is the shared sink every
 * per-transfer effective bandwidth EWMAs for the storage and cache
   channels (bytes/s, stall time included);
 * per-form serve counts (which tier answered each lookup);
-* bounded-queue depth/occupancy gauges from the stage-parallel executor
-  (ingestion backpressure: a queue pinned at 1.0 occupancy names the
-  stage the repartition controller should be feeding);
 * error counters (``refill`` / ``prefetch`` / ...) so background-thread
   failures surface in ``stats()`` instead of vanishing.
 
@@ -34,12 +31,8 @@ Notes on estimator semantics:
 * CPU rates are *node-aggregate* samples/s: per-sample latency EWMAs are
   scaled by the registered worker concurrency (``add_concurrency`` /
   ``remove_concurrency``, called by pipelines on start/stop), mirroring
-  how Table 3 measures t_DA with all cores busy.  A stage can report its
-  own worker count (``record_stage(..., workers=)``) when not every
-  registered worker runs it — the stage-parallel executor's augment
-  stage is a single thread, its decode group is elastically sized — and
-  the aggregate rate then uses the per-stage counts (pipelined:
-  ``t_da = min(w_dec/dec, w_aug/aug)``) instead of the global scale.
+  how Table 3 measures t_DA with all cores busy:
+  ``t_da = conc/(dec+aug)`` and ``t_a = conc/aug``.
 * Bandwidths are per-transfer effective rates.  Under a shared
   token-bucket (``RemoteStorage``) each transfer already observes its
   contended share, so the EWMA approximates the per-stream bandwidth and
@@ -113,8 +106,6 @@ class TelemetrySnapshot:
     bandwidth_n: Dict[str, int]
     serve_counts: Dict[str, int]                # per-form + "storage"
     concurrency: int
-    queue_depth: Dict[str, float] = field(default_factory=dict)
-    queue_occupancy: Dict[str, float] = field(default_factory=dict)
     errors: Dict[str, int] = field(default_factory=dict)
     t_da: Optional[float] = None                # samples/s, decode+augment
     t_a: Optional[float] = None                 # samples/s, augment-only
@@ -143,17 +134,13 @@ class TelemetryAggregator:
 
     def __init__(self, alpha: float = 0.2):
         self._lock = threading.Lock()
-        self._alpha = float(alpha)
         self._stages: Dict[str, Ewma] = {s: Ewma(alpha) for s in STAGES}
         self._bw: Dict[str, Ewma] = {c: Ewma(alpha) for c in CHANNELS}
         self._channel_bytes: Dict[str, int] = {c: 0 for c in CHANNELS}
         self._serves: Dict[str, int] = {
             "encoded": 0, "decoded": 0, "augmented": 0, "storage": 0}
         self._concurrency = 0
-        self._queue_depth: Dict[str, Ewma] = {}
-        self._queue_occ: Dict[str, Ewma] = {}
         self._errors: Dict[str, int] = {}
-        self._stage_workers: Dict[str, int] = {}
         # open-loop request accounting: outcome counters + raw latency
         # samples (exact percentiles need the full set, not an EWMA).
         # Bounded so an unbounded serve run cannot grow memory without
@@ -178,23 +165,12 @@ class TelemetryAggregator:
         with self._lock:
             self._concurrency = max(0, self._concurrency - int(n))
 
-    def record_stage(self, stage: str, seconds: float, n: int = 1,
-                     workers: Optional[int] = None) -> None:
-        """Record ``n`` samples taking ``seconds`` total in ``stage``.
-
-        ``workers`` declares how many threads run this stage when that
-        differs from the registered global concurrency (the per-sample
-        executor's pool runs every stage on every worker; the
-        stage-parallel executor's stages have their own group sizes).
-        Last writer wins — an approximation when executors mix on one
-        service.
-        """
+    def record_stage(self, stage: str, seconds: float, n: int = 1) -> None:
+        """Record ``n`` samples taking ``seconds`` total in ``stage``."""
         if n <= 0 or stage not in self._stages:
             return
         with self._lock:
             self._stages[stage].update(seconds / n)
-            if workers is not None:
-                self._stage_workers[stage] = max(int(workers), 1)
 
     def record_bytes(self, channel: str, nbytes: int,
                      seconds: float) -> None:
@@ -219,25 +195,6 @@ class TelemetryAggregator:
         key = form if form in self._serves else "storage"
         with self._lock:
             self._serves[key] += 1
-
-    def record_queue(self, name: str, depth: int, capacity: int) -> None:
-        """Gauge one bounded pipeline queue: current depth + occupancy
-        (depth/capacity).  Occupancy ~1.0 means the downstream stage is
-        the bottleneck (ingestion backpressure)."""
-        with self._lock:
-            if name not in self._queue_depth:
-                self._queue_depth[name] = Ewma(self._alpha)
-                self._queue_occ[name] = Ewma(self._alpha)
-            self._queue_depth[name].update(depth)
-            self._queue_occ[name].update(depth / max(capacity, 1))
-
-    def clear_stage_workers(self, *stages: str) -> None:
-        """Forget per-stage worker counts (a stopped stage-parallel
-        executor must not leave its group sizes scaling latencies that a
-        per-sample pipeline reports afterwards)."""
-        with self._lock:
-            for stage in stages or tuple(self._stage_workers):
-                self._stage_workers.pop(stage, None)
 
     def record_coalesced(self, wait_s: float) -> None:
         """Count one production avoided by joining an in-flight one
@@ -316,12 +273,7 @@ class TelemetryAggregator:
             bw_n = {c: e.n for c, e in self._bw.items()}
             serves = dict(self._serves)
             conc = max(self._concurrency, 1)
-            q_depth = {k: e.value for k, e in self._queue_depth.items()
-                       if e.value is not None}
-            q_occ = {k: e.value for k, e in self._queue_occ.items()
-                     if e.value is not None}
             errors = dict(self._errors)
-            sw = dict(self._stage_workers)
             ch_bytes = dict(self._channel_bytes)
 
         def rate(total_latency: Optional[float]) -> Optional[float]:
@@ -330,16 +282,9 @@ class TelemetryAggregator:
             return conc / total_latency
 
         dec, aug = lat["decode"], lat["augment"]
-        w_dec, w_aug = sw.get("decode"), sw.get("augment")
-        if dec and aug and (w_dec or w_aug):
-            # stage-parallel reporters: decode and augment run on their
-            # own worker groups, pipelined — the chain rate is the
-            # slower stage's, not conc/(dec+aug)
-            t_da = min((w_dec or conc) / dec, (w_aug or conc) / aug)
-        else:
-            t_da = rate((dec + aug) if dec is not None and aug is not None
-                        else None)
-        t_a = (w_aug / aug) if aug and w_aug else rate(aug)
+        t_da = rate((dec + aug) if dec is not None and aug is not None
+                    else None)
+        t_a = rate(aug)
         counts = {
             "t_da": min(lat_n["decode"], lat_n["augment"]),
             "t_a": lat_n["augment"],
@@ -351,7 +296,7 @@ class TelemetryAggregator:
         return TelemetrySnapshot(
             stage_latency=lat, stage_n=lat_n, bandwidth=bw,
             bandwidth_n=bw_n, serve_counts=serves, concurrency=conc,
-            queue_depth=q_depth, queue_occupancy=q_occ, errors=errors,
+            errors=errors,
             t_da=t_da, t_a=t_a,
             b_storage=bw["storage"], b_cache=bw["cache"],
             b_disk=bw["disk"], b_hbm=bw["h2d"], counts=counts,
@@ -375,8 +320,6 @@ class TelemetryAggregator:
             "serve_counts": dict(snap.serve_counts),
             "hit_rates": snap.hit_rates(),
             "concurrency": snap.concurrency,
-            "queue_depth": dict(snap.queue_depth),
-            "queue_occupancy": dict(snap.queue_occupancy),
             "errors": dict(snap.errors),
             "t_da": snap.t_da, "t_a": snap.t_a,
             "b_storage": snap.b_storage, "b_cache": snap.b_cache,

@@ -748,8 +748,8 @@ class TieredCache:
         """Batch-granular admission: ``entries`` is a sequence of
         ``(key, value, nbytes)``; the capacity vote + insert for each
         stripe's slice of the batch runs under ONE acquisition of that
-        stripe's lock (the stage-parallel pipeline's per-batch admission
-        — vs one acquisition per sample).
+        stripe's lock (the device route's per-batch admission of its
+        freshly augmented rows — vs one acquisition per sample).
 
         Per-entry semantics are identical to :meth:`insert_gated`: each
         entry is voted with the partition state the previous entries

@@ -9,11 +9,12 @@ so the paper's concurrency experiments run for real on CPU::
     pipe = DSIPipeline(server.open_session(batch_size=32), storage)
     batch = pipe.next_batch()
 
-Three executors (the ``executor=`` knob):
+Two routes (the ``executor=`` argument):
 
-* ``"per-sample"`` (default, the seed behavior): every sample runs
-  fetch->decode->augment serially inside one worker, ``next_batch`` is a
-  synchronous barrier over the whole batch.
+* ``"per-sample"`` (default): the host route.  Every sample runs
+  lookup->fetch->decode->augment->admit inside one worker of a small
+  pool, and ``next_batch`` is a synchronous barrier over the whole
+  batch.  It is the VirtualClock reference of the workload runner.
 * ``"device"``: device-resident preprocessing — encoded samples go
   through the fused Pallas decode+augment kernel
   (:func:`repro.kernels.augment.ops.decode_augment_batch_seeded`) in one
@@ -27,27 +28,16 @@ Three executors (the ``executor=`` knob):
   (VirtualClock-deterministic with ``sync_refills``); requires a
   dataset whose ``decode`` is the counter-hash
   ``SyntheticDataset.decode`` (see :func:`fused_decode_seed`).
-* ``"stage-parallel"``: a decoupled asynchronous executor — bounded
-  queues between sampler -> fetch -> decode -> augment -> collate,
-  per-stage worker groups sized from the service telemetry's stage EWMAs
-  (:func:`plan_stage_workers`), an augment stage that batches decoded
-  samples through the service's vectorized
-  :class:`~repro.api.backends.AugmentBackend` (Pallas kernel or NumPy
-  loop), and batch-granular cache admission (one lock acquisition per
-  admitted batch via ``Session.admit_batch``).  Batches are emitted in
-  sampling order; batch N+1's storage fetches overlap batch N's
-  decode/augment, so throughput approaches the slowest *stage* instead
-  of the per-batch sum (benchmarks/fig_pipeline_throughput.py).
 
-Under any executor, ``start_prefetch()`` builds batches ahead of the
+On either route, ``start_prefetch()`` builds batches ahead of the
 caller on a thread of the pipeline's own and ``get()`` takes them, in
-the order ``next_batch`` would have given them; the stage-parallel
-executor is its own prefetcher.
+the order ``next_batch`` would have given them, so batch n+1's storage
+reads and kernel launch overlap the caller's work on batch n.
 
-Both executors produce identical tensors for a given (epoch, sample id):
-augmentation parameters derive from per-sample seeds, not executor
-scheduling.  Batches carry an additive ``"ids"`` key with the sample ids
-in slot order.
+Augmentation parameters derive from per-sample seeds, not from the
+route or its scheduling, so a given (epoch, sample id) gets the same
+crop and flip on both routes.  Batches carry an additive ``"ids"`` key
+with the sample ids in slot order.
 
 Cache admission goes through the service's :class:`AdmissionPolicy` hooks
 (capacity is voted under the cache lock, atomically with the insert) —
@@ -70,19 +60,19 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.api.server import SenecaService, Session, SessionClosed
+from repro.api.server import SenecaService, Session
 from repro.data.augment import augment_np
 from repro.data.storage import RemoteStorage
 from repro.data.synthetic import SyntheticDataset
 
 log = logging.getLogger(__name__)
 
-EXECUTORS = ("per-sample", "stage-parallel", "device")
+EXECUTORS = ("per-sample", "device")
 
 
 def _aug_seed(epoch_tag: int, sid: int) -> int:
-    """The per-sample augmentation seed — shared by every executor and
-    both augment backends, so batch composition never changes content."""
+    """The per-sample augmentation seed — shared by both routes, so
+    batch composition never changes content."""
     return (epoch_tag * 1_000_003 + sid) & 0x7FFFFFFF
 
 
@@ -181,487 +171,6 @@ class StageTimes:
                 if f.name != "now"}
 
 
-def plan_stage_workers(telemetry, n_workers: int) -> Tuple[int, int]:
-    """Size the (fetch, decode) worker groups from the telemetry stage
-    EWMAs.
-
-    The ``n_workers`` budget is split proportionally to the observed
-    storage-fetch vs decode latencies (clamped to >= 1 each; an even
-    split until both signals exist, with a budget floor of 2).  The
-    fetch share is then doubled: fetch workers spend most of their time
-    parked in storage waits (token bucket / network), so 2x
-    oversubscription keeps the storage channel busy through the GIL
-    pauses of the CPU stages — decode keeps the plain CPU share.  The
-    stage-parallel executor re-plans this every batch as the EWMAs move
-    (elastic groups), so a pipeline that starts cache-cold and becomes
-    decode-bound sheds fetch workers live.
-    """
-    total = max(int(n_workers), 2)
-    lat = telemetry.snapshot().stage_latency
-    fetch, decode = lat.get("fetch_storage"), lat.get("decode")
-    if not fetch or not decode:
-        base_fetch = max(total // 2, 1)
-    else:
-        base_fetch = int(round(total * fetch / (fetch + decode)))
-        base_fetch = min(max(base_fetch, 1), total - 1)
-    return 2 * base_fetch, total - base_fetch
-
-
-class _Assembly:
-    """One in-flight batch: slots fill in as samples finish their route.
-
-    ``arrived`` is touched only by the single augment-stage thread (every
-    sample's route ends there, pre-augmented cache hits included), which
-    is what makes batch completion race-free without a per-batch lock.
-    """
-
-    __slots__ = ("seq", "ids", "epoch", "out", "arrived")
-
-    def __init__(self, seq: int, ids: List[int], epoch: int):
-        self.seq = seq
-        self.ids = ids
-        self.epoch = epoch
-        self.out: List[Optional[np.ndarray]] = [None] * len(ids)
-        self.arrived = 0
-
-
-class _StageParallelExecutor:
-    """Queue-fed stage pipeline over one DSIPipeline's session/storage.
-
-    Thread layout: 1 sampler, ``n_fetch`` fetch workers, ``n_decode``
-    decode workers, 1 augment (vectorized, batch-granular admission),
-    1 collate (in-order emission, refill + repartition ticks).  Bounded
-    queues propagate consumer backpressure all the way to the sampler;
-    every put/get is stop-aware so teardown never deadlocks.
-    """
-
-    def __init__(self, pipe: "DSIPipeline", out_depth: int):
-        self.pipe = pipe
-        bs = pipe.bs
-        self._stop = threading.Event()
-        self.error: Optional[BaseException] = None
-        self._session_closed = False
-        self.fetch_q: "queue.Queue" = queue.Queue(maxsize=2 * bs)
-        self.decode_q: "queue.Queue" = queue.Queue(maxsize=2 * bs)
-        self.augment_q: "queue.Queue" = queue.Queue(maxsize=2 * bs)
-        self.collate_q: "queue.Queue" = queue.Queue(maxsize=out_depth + 1)
-        self.out_q: "queue.Queue" = queue.Queue(maxsize=max(out_depth, 1))
-        # elastic worker groups: live/target counts per resizable stage.
-        # The collate thread re-plans targets from telemetry every batch;
-        # surplus workers retire themselves, missing ones are spawned.
-        self._group_lock = threading.Lock()
-        self._live = {"fetch": 0, "decode": 0}
-        self._target = dict(zip(("fetch", "decode"), plan_stage_workers(
-            pipe.telemetry, pipe._n_workers)))
-        self._last_plan = dict(self._target)
-        self._group_loops = {"fetch": self._fetch_loop,
-                             "decode": self._decode_loop}
-        self._threads: List[threading.Thread] = []
-        for target, name in ((self._sampler_loop, "sampler"),
-                             (self._augment_loop, "augment"),
-                             (self._collate_loop, "collate")):
-            t = threading.Thread(target=target, daemon=True,
-                                 name=f"dsi-{name}")
-            self._threads.append(t)
-            t.start()
-        self._reconcile_groups()
-
-    # -- elastic worker groups -----------------------------------------
-    def worker_counts(self) -> Dict[str, int]:
-        with self._group_lock:
-            return dict(self._live)
-
-    def _resize_groups(self) -> None:
-        """Re-plan the fetch/decode group sizes from the current stage
-        EWMAs (collate thread, once per batch), debounced: a new plan is
-        applied only when two consecutive batches agree on it, so EWMA
-        jitter flapping across a rounding boundary cannot churn worker
-        threads every batch, while any persistent shift in the stage
-        balance lands within two batches."""
-        planned = dict(zip(("fetch", "decode"), plan_stage_workers(
-            self.pipe.telemetry, self.pipe._n_workers)))
-        with self._group_lock:
-            if planned == self._last_plan:
-                self._target.update(planned)
-            self._last_plan = planned
-        self._reconcile_groups()
-
-    def _reconcile_groups(self) -> None:
-        """Spawn workers up to the group targets (retiring is the worker
-        loops' own job) and drop finished threads from the join list so
-        it cannot grow without bound across retarget cycles."""
-        spawn: List[str] = []
-        with self._group_lock:
-            for group, tgt in self._target.items():
-                while self._live[group] < tgt:
-                    self._live[group] += 1
-                    spawn.append(group)
-        self._threads = [t for t in self._threads if t.is_alive()]
-        for group in spawn:
-            t = threading.Thread(target=self._group_loops[group],
-                                 daemon=True, name=f"dsi-{group}")
-            self._threads.append(t)
-            t.start()
-
-    def _surplus(self, group: str) -> bool:
-        """True when this worker should retire (its group shrank)."""
-        with self._group_lock:
-            if self._live[group] > self._target[group]:
-                self._live[group] -= 1
-                return True
-        return False
-
-    # -- stop-aware queue plumbing -------------------------------------
-    def _put(self, q: "queue.Queue", item) -> bool:
-        while not self._stop.is_set():
-            try:
-                q.put(item, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
-        return False
-
-    def _get(self, q: "queue.Queue"):
-        while not self._stop.is_set():
-            try:
-                return q.get(timeout=0.1)
-            except queue.Empty:
-                continue
-        return None
-
-    def _fail(self, exc: BaseException) -> None:
-        """First failure wins: record, surface in telemetry, halt the
-        executor (an incomplete assembly can never collate, so limping
-        on would just hang the consumer)."""
-        if self.error is None:
-            self.error = exc
-        if self.pipe.telemetry.record_error("pipeline") == 1:
-            log.warning("stage-parallel executor failed; first error:",
-                        exc_info=exc)
-        self._stop.set()
-
-    # -- stages --------------------------------------------------------
-    def _sampler_loop(self) -> None:
-        seq = 0
-        pipe = self.pipe
-        while not self._stop.is_set():
-            try:
-                ids, _forms = pipe.session.next_batch_ids()
-            except SessionClosed:
-                # normal lifecycle, not a failure — but the consumer must
-                # fail fast like the per-sample executor does, not block
-                # out a full get_batch timeout on a drained queue
-                self._session_closed = True
-                self._stop.set()
-                return
-            except Exception as e:      # noqa: BLE001 - recorded, not lost
-                self._fail(e)
-                return
-            asm = _Assembly(seq, [int(x) for x in ids], pipe.session.epoch)
-            seq += 1
-            for slot in range(len(asm.ids)):
-                if not self._put(self.fetch_q, (asm, slot)):
-                    return
-
-    def _fetch_loop(self) -> None:
-        pipe = self.pipe
-        tel = pipe.telemetry
-        while not self._stop.is_set():
-            if self._surplus("fetch"):
-                return
-            item = self._get(self.fetch_q)
-            if item is None:
-                return
-            asm, slot = item
-            sid = asm.ids[slot]
-            try:
-                t_look = pipe._now()
-                form, value, tier = pipe.session.lookup_tiered(sid)
-                tel.record_serve(form)
-                t0 = pipe._now()
-                if form is None:
-                    ok = self._fetch_miss(asm, slot, sid)
-                else:
-                    pipe.times.fetch += t0 - t_look
-                    tel.record_stage("fetch_cache", t0 - t_look)
-                    nbytes = value.nbytes if hasattr(value, "nbytes") \
-                        else len(value)
-                    # spill-tier hits calibrate b_disk, DRAM hits b_cache
-                    tel.record_bytes("disk" if tier == "disk" else "cache",
-                                     nbytes, t0 - t_look)
-                    if form == "augmented":
-                        ok = self._put(self.augment_q,
-                                       (asm, slot, value, None, False, True,
-                                        None))
-                    elif form == "decoded":
-                        ok = self._put(self.augment_q,
-                                       (asm, slot, value, None, False,
-                                        False, None))
-                    else:                        # encoded cache hit
-                        ok = self._put(self.decode_q,
-                                       (asm, slot, value, False, None))
-                if not ok:
-                    return
-            except Exception as e:      # noqa: BLE001
-                self._fail(e)
-                return
-
-    def _fetch_miss(self, asm: "_Assembly", slot: int, sid: int) -> bool:
-        """Storage-miss path of the fetch stage, single-flight aware:
-        the leader fetches and carries its flight through decode ->
-        augment (finished with the augmented row in `_augment_group`);
-        joiners receive the finished value and skip straight to the
-        pre-augmented queue."""
-        pipe = self.pipe
-        tel = pipe.telemetry
-        prod = pipe._production
-        flight = None
-        while prod is not None:
-            leader, flight = prod.begin(sid, "augmented")
-            if leader:
-                break            # flight is None in observe mode
-            t_j = pipe._now()
-            ok, joined = prod.join(flight, pipe._clock)
-            if ok:
-                tel.record_coalesced(max(pipe._now() - t_j, 0.0))
-                return self._put(self.augment_q,
-                                 (asm, slot, joined, None, False, True,
-                                  None))
-            if not flight.done:
-                # wait declined or timed out: produce ourselves
-                flight = None
-                break
-            # leader aborted: retry begin(); the first retrier leads
-        t0 = pipe._now()
-        try:
-            enc = pipe.storage.fetch(sid)
-        except BaseException:
-            if prod is not None:
-                prod.abort(flight)
-            raise
-        dt = pipe._now() - t0
-        pipe.times.fetch += dt
-        tel.record_stage("fetch_storage", dt)
-        tel.record_bytes("storage", len(enc), dt)
-        ok = self._put(self.decode_q, (asm, slot, enc, True, flight))
-        if not ok and prod is not None:
-            prod.abort(flight)   # shutting down: don't strand joiners
-        return ok
-
-    def _decode_loop(self) -> None:
-        pipe = self.pipe
-        while not self._stop.is_set():
-            if self._surplus("decode"):
-                return
-            item = self._get(self.decode_q)
-            if item is None:
-                return
-            asm, slot, enc, from_storage, flight = item
-            try:
-                t1 = pipe._now()
-                img = pipe.ds.decode(enc, asm.ids[slot])
-                dt = pipe._now() - t1
-                pipe.times.decode += dt
-                # unlocked _live read: an approximate worker count is
-                # fine for the calibration scale factor
-                pipe.telemetry.record_stage(
-                    "decode", dt, workers=max(self._live["decode"], 1))
-                # carry enc along only when it still needs admission, so
-                # the augment stage can batch-admit the encoded form too
-                if not self._put(self.augment_q,
-                                 (asm, slot, img,
-                                  enc if from_storage else None, True,
-                                  False, flight)):
-                    if pipe._production is not None:
-                        pipe._production.abort(flight)
-                    return
-            except Exception as e:      # noqa: BLE001
-                if pipe._production is not None:
-                    pipe._production.abort(flight)
-                self._fail(e)
-                return
-
-    def _augment_loop(self) -> None:
-        pipe = self.pipe
-        sess = pipe.session
-        # per-assembly buffers of samples awaiting vectorized augmentation:
-        # seq -> [(slot, img, enc_to_admit, admit_decoded, flight)]
-        buffers: Dict[int, List] = {}
-        while not self._stop.is_set():
-            item = self._get(self.augment_q)
-            if item is None:
-                return
-            asm, slot, payload, enc, admit_dec, pre, flight = item
-            try:
-                if pre:
-                    asm.out[slot] = payload
-                else:
-                    buffers.setdefault(asm.seq, []).append(
-                        (slot, payload, enc, admit_dec, flight))
-                asm.arrived += 1
-                if asm.arrived < len(asm.ids):
-                    continue
-                group = buffers.pop(asm.seq, [])
-                if group:
-                    self._augment_group(sess, asm, group)
-                if not self._put(self.collate_q, asm):
-                    return
-            except Exception as e:      # noqa: BLE001
-                self._fail(e)
-                return
-
-    def _augment_group(self, sess: Session, asm: _Assembly,
-                       group: List) -> None:
-        """Vectorized augment + batch-granular admission for the samples
-        of one assembly that were not served pre-augmented."""
-        pipe = self.pipe
-        try:
-            self._augment_group_inner(sess, asm, group)
-        except BaseException:
-            prod = pipe._production
-            if prod is not None:
-                # no flight was finished yet (the hand-off loop is the
-                # inner body's last step): wake every joiner to retry
-                for _slot, _img, _enc, _ad, fl in group:
-                    prod.abort(fl)
-            raise
-
-    def _augment_group_inner(self, sess: Session, asm: _Assembly,
-                             group: List) -> None:
-        pipe = self.pipe
-        enc_entries = [(asm.ids[slot], enc, len(enc))
-                       for slot, _img, enc, _ad, _fl in group
-                       if enc is not None]
-        if enc_entries:
-            sess.admit_batch("encoded", enc_entries)
-        dec_entries = [(asm.ids[slot], img, img.nbytes)
-                       for slot, img, _enc, ad, _fl in group if ad]
-        if dec_entries:
-            sess.admit_batch("decoded", dec_entries)
-        slots = [slot for slot, _img, _enc, _ad, _fl in group]
-        imgs = np.stack([img for _slot, img, _enc, _ad, _fl in group])
-        seeds = np.asarray([_aug_seed(asm.epoch, asm.ids[s]) for s in slots],
-                           np.int64)
-        t2 = pipe._now()
-        outs = pipe.augment.augment_batch(imgs, pipe.ds.crop_hw, seeds)
-        dt = pipe._now() - t2
-        pipe.times.augment += dt
-        # the augment stage is one thread, not the whole worker pool:
-        # report that, or calibrate() would overestimate t_a ~n_workers x
-        pipe.telemetry.record_stage("augment", dt, n=len(slots), workers=1)
-        # np.array copies: cached rows must not pin the whole batch
-        # array.  Pre-vote the metadata half of admission so the copies
-        # are only built for entries the policy would take — under
-        # unseen-only admission a single-session pipeline's own samples
-        # are all already seen, so this skips B row copies per batch
-        if pipe.svc.tier_capacity("augmented") > 0:
-            ids = [asm.ids[s] for s in slots]
-            wanted = pipe.svc.admission_votes("augmented", ids)
-            entries = [(sid, np.array(outs[i]), outs[i].nbytes)
-                       for i, (sid, w) in enumerate(zip(ids, wanted)) if w]
-            if entries:
-                sess.admit_batch("augmented", entries)
-        for i, s in enumerate(slots):
-            asm.out[s] = outs[i]
-        prod = pipe._production
-        if prod is not None:
-            for i, (_slot, _img, _enc, _ad, fl) in enumerate(group):
-                if fl is not None:
-                    # np.array copy: the handed-off row must not pin
-                    # the whole batch array in every joiner's cache
-                    prod.finish(fl, np.array(outs[i]))
-
-    def _collate_loop(self) -> None:
-        pipe = self.pipe
-        pending: Dict[int, _Assembly] = {}
-        next_seq = 0
-        while not self._stop.is_set():
-            asm = self._get(self.collate_q)
-            if asm is None:
-                return
-            try:
-                pending[asm.seq] = asm
-                while next_seq in pending:     # emit in sampling order
-                    asm = pending.pop(next_seq)
-                    t0 = pipe._now()
-                    batch = {
-                        # copy=False: backends return float32 already —
-                        # don't re-copy the whole batch on the one
-                        # thread that serializes emission
-                        "images": np.stack(asm.out).astype(np.float32,
-                                                           copy=False),
-                        "labels": np.asarray(
-                            [pipe.ds.label(s) for s in asm.ids], np.int32),
-                        "ids": np.asarray(asm.ids, np.int64),
-                    }
-                    dt = pipe._now() - t0
-                    pipe.times.collate += dt
-                    pipe.telemetry.record_stage("collate", dt,
-                                                n=len(asm.ids))
-                    pipe.times.batches += 1
-                    pipe._process_refills()
-                    pipe.svc.maybe_repartition()
-                    self._gauge_queues()
-                    self._resize_groups()
-                    if not self._put(self.out_q, batch):
-                        return
-                    next_seq += 1
-            except Exception as e:      # noqa: BLE001 - same contract as
-                self._fail(e)           # every other stage loop: no
-                return                  # silent thread death
-
-    def _gauge_queues(self) -> None:
-        tel = self.pipe.telemetry
-        for name, q in (("fetch", self.fetch_q), ("decode", self.decode_q),
-                        ("augment", self.augment_q),
-                        ("collate", self.collate_q), ("out", self.out_q)):
-            tel.record_queue(name, q.qsize(), q.maxsize)
-
-    # -- consumer side -------------------------------------------------
-    def get_batch(self,
-                  timeout: Optional[float] = 60.0
-                  ) -> Dict[str, np.ndarray]:
-        """Next collated batch.  ``timeout=None`` blocks until one is
-        ready (``next_batch`` semantics — a slow pipeline is not an
-        error); a finite timeout raises ``queue.Empty`` at the deadline
-        (``get`` semantics, matching the per-sample prefetch queue).
-
-        The inner poll is capped at the *remaining* deadline, never a
-        fixed quantum: a finite ``timeout < 0.2`` used to overshoot by
-        up to a full 0.2 s poll interval before the deadline was even
-        checked."""
-        deadline = float("inf") if timeout is None \
-            else time.monotonic() + timeout
-        while True:
-            wait = min(0.2, deadline - time.monotonic()) \
-                if deadline != float("inf") else 0.2
-            try:
-                return self.out_q.get(timeout=max(wait, 0.0))
-            except queue.Empty:
-                if self.error is not None:
-                    raise RuntimeError(
-                        "stage-parallel pipeline failed; see telemetry "
-                        "errors") from self.error
-                if self._session_closed:
-                    raise SessionClosed(
-                        "session closed while the stage-parallel "
-                        "pipeline was running; open a new one with "
-                        "SenecaServer.open_session()")
-                if self._stop.is_set():
-                    raise RuntimeError(
-                        "stage-parallel pipeline is stopped")
-                if time.monotonic() >= deadline:
-                    raise
-
-    def stop(self) -> None:
-        self._stop.set()
-        for t in list(self._threads):
-            t.join(timeout=2.0)
-        # don't leave this executor's group sizes scaling latencies that
-        # a per-sample pipeline on the same service reports afterwards
-        self.pipe.telemetry.clear_stage_workers("decode", "augment")
-
-
 def _assemble(groups, singles, order):
     """The group outputs, then the single rows, concatenated and
     gathered by ``order`` into slot order, as float32."""
@@ -708,9 +217,8 @@ class DSIPipeline:
     def __init__(self, session, storage: Optional[RemoteStorage] = None,
                  *legacy_storage, batch_size: Optional[int] = None,
                  n_workers: int = 4, prefetch: int = 2, seed: int = 0,
-                 executor: str = "per-sample", augment_backend=None,
-                 consume_hook=None, sync_refills: bool = False,
-                 clock=None):
+                 executor: str = "per-sample", consume_hook=None,
+                 sync_refills: bool = False, clock=None):
         # validate before any side effect: the legacy path below
         # registers a job on the shared service, which must not leak
         # when construction fails
@@ -769,19 +277,12 @@ class DSIPipeline:
         # service doubles in tests) — consulted before producing a miss
         self._production = getattr(self.svc, "production", None)
         # telemetry feeds the adaptive repartition loop: per-stage EWMAs,
-        # transfer bandwidths, per-form serve counts and (stage-parallel)
-        # queue gauges, aggregated across every pipeline on the service
+        # transfer bandwidths and per-form serve counts, aggregated
+        # across every pipeline on the service
         self.telemetry = self.svc.telemetry
         self._n_workers = n_workers
         self.telemetry.add_concurrency(n_workers)
         self.rng = np.random.default_rng(seed + self.session.job_id)
-        # batched augmentation engine (stage-parallel augment stage):
-        # service-level knob, overridable per pipeline
-        if augment_backend is None:
-            self.augment = self.svc.augment
-        else:
-            from repro.api.backends import resolve_augment_backend
-            self.augment = resolve_augment_backend(augment_backend)
         # consumer-rate hook: called with every batch ``next_batch`` or
         # ``get`` hands out, on the caller's thread, before it returns.
         # The WorkloadRunner installs a rate limiter here to emulate GPU
@@ -791,13 +292,10 @@ class DSIPipeline:
         # calling thread instead of racing them on the worker pool
         # (required for byte-identical virtual-clock workload runs)
         self._sync_refills = sync_refills
-        self._prefetch_depth = prefetch
         self._q: "queue.Queue" = queue.Queue(maxsize=prefetch)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._prefetch_exc: Optional[BaseException] = None
-        self._executor: Optional[_StageParallelExecutor] = None
-        self._executor_lock = threading.Lock()
 
     # ------------------------------------------------------------------
     def _produce_sample(self, sid: int, epoch_tag: int) -> np.ndarray:
@@ -899,11 +397,7 @@ class DSIPipeline:
         if producer and threading.current_thread() is not self._thread:
             raise RuntimeError("the prefetch thread runs this pipeline's "
                                "route; take its batches with get()")
-        if self.executor == "stage-parallel":
-            # block until produced, like the per-sample path: slowness is
-            # backpressure, not failure (errors still raise immediately)
-            batch = self._ensure_executor().get_batch(timeout=None)
-        elif self.executor == "device":
+        if self.executor == "device":
             batch = self._next_batch_device()
         else:
             batch = self._next_batch_host()
@@ -1176,24 +670,12 @@ class DSIPipeline:
                     "stats()['refill_errors'])", sid, exc_info=True)
 
     # ------------------------------------------------------------------
-    def _ensure_executor(self) -> _StageParallelExecutor:
-        with self._executor_lock:
-            if self._executor is None:
-                self._executor = _StageParallelExecutor(
-                    self, out_depth=max(self._prefetch_depth, 1))
-            return self._executor
-
     def start_prefetch(self) -> None:
         """Build batches ahead of the caller, up to ``prefetch`` queued
         and one more held, on a thread of the pipeline's own; take them
         with :meth:`get`.  A second call finds the thread running and
         does nothing.  From then on only that thread runs the route:
         :meth:`next_batch` from any other thread is an error."""
-        if self.executor == "stage-parallel":
-            # the stage executor IS the prefetcher: out_q holds up to
-            # ``prefetch`` collated batches
-            self._ensure_executor()
-            return
         if self._thread is not None:
             return
 
@@ -1234,13 +716,8 @@ class DSIPipeline:
         those already queued when asked for.  The consume hook fires
         here, on the caller's thread, with each batch handed out."""
         with self.times.span("wait"):
-            if self.executor == "stage-parallel":
-                executor = self._ensure_executor()
-                ready = not executor.out_q.empty()
-                batch = executor.get_batch(timeout)
-            else:
-                ready = not self._q.empty()
-                batch = self._take(timeout)
+            ready = not self._q.empty()
+            batch = self._take(timeout)
         self.times.taken += 1
         self.times.ready += ready
         if self._consume_hook is not None:
@@ -1281,8 +758,6 @@ class DSIPipeline:
             # it ends after the batch in hand: join before the session
             # closes under it
             self._thread.join()
-        if self._executor is not None:
-            self._executor.stop()
         self.pool.shutdown(wait=False)
         if close_session:
             self.session.close()

@@ -265,10 +265,8 @@ class WorkloadRunner:
             bad = [s.name for s in trace if s.executor != "per-sample"]
             if bad:
                 raise ValueError(
-                    f"virtual-clock runs require executor='per-sample' "
-                    f"(jobs {bad} use the stage-parallel executor, whose "
-                    f"free-running stage threads would race past the "
-                    f"clock's turn discipline)")
+                    f"virtual-clock runs require executor='per-sample', "
+                    f"the reference route (jobs {bad} ask for another)")
         if self.faults:
             bad_jobs = [f.job for f in self.faults
                         if f.job is not None and f.job not in names]

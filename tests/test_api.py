@@ -238,7 +238,7 @@ def test_parity_numpy_vs_jax_same_stream_same_aggregates():
 
 
 # ----------------------------------------------------------------------
-# batch-granular admission (the stage-parallel executor's path): same
+# batch-granular admission (the device route's path): same
 # policy decisions as N per-sample admits, one lock acquisition per batch
 def test_admit_batch_matches_per_sample_admission():
     """Same entries, same policies, same capacity: admit_batch and a loop

@@ -301,26 +301,30 @@ def test_per_sample_get_honors_sub_poll_timeout():
         server.close()
 
 
-class _SlowEncodeDataset(SyntheticDataset):
-    """First fetch takes ~0.3s of wall time (stage-parallel pipelines
-    cannot emit a batch inside a 50ms get_batch timeout)."""
+class _SlowStorage(RemoteStorage):
+    """Every fetch takes ~0.3s of wall time (a route cannot build a
+    batch of 8 inside a 50ms get timeout).  The slowness sits in the
+    storage, not the dataset: the device route takes only the plain
+    counter-hash SyntheticDataset."""
 
-    def encoded(self, sample_id: int) -> bytes:
+    def fetch(self, sample_id: int) -> bytes:
         time.sleep(0.3)
-        return super().encoded(sample_id)
+        return super().fetch(sample_id)
 
 
-def test_stage_parallel_get_batch_honors_sub_poll_timeout():
-    ds = _SlowEncodeDataset("slow", 32, 24_000, image_hw=(64, 64),
-                            crop_hw=(56, 56), n_classes=100)
+def test_device_prefetch_get_honors_sub_poll_timeout():
+    ds = SyntheticDataset("slow", 32, 24_000, image_hw=(64, 64),
+                          crop_hw=(56, 56), n_classes=100)
     server = _server(ds)
     pipe = DSIPipeline(server.open_session(batch_size=8),
-                       RemoteStorage(ds), executor="stage-parallel")
+                       _SlowStorage(ds), executor="device")
     try:
+        pipe.start_prefetch()
         t0 = time.monotonic()
         with pytest.raises(queue.Empty):
             pipe.get(timeout=0.05)
         assert time.monotonic() - t0 < 0.15
+        assert pipe._thread.is_alive()          # still fetching
     finally:
         pipe.stop()
         server.close()

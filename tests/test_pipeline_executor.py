@@ -1,25 +1,35 @@
-"""Stage-parallel DSI executor + prefetch-loop bugfixes.
+"""DSI pipeline routes + prefetch-loop bugfixes.
 
-Covers the ISSUE-3 contract: the prefetch queue neither drops nor
-duplicates batches under a slow consumer, prefetch/refill failures are
-recorded instead of swallowed, cache-hit fetch time is accounted as the
-lookup interval, the batched augment backends (NumPy loop vs Pallas
-kernel) agree within float tolerance with per-sample seed determinism,
-and the stage-parallel executor preserves epoch semantics while emitting
-batches in sampling order.
+The prefetch queue neither drops nor duplicates batches under a slow
+consumer, prefetch/refill failures are recorded instead of swallowed,
+cache-hit fetch time is accounted as the lookup interval, the NumPy and
+Pallas batch augments agree within float tolerance with per-sample seed
+determinism, both routes (``"per-sample"``, ``"device"``) cover an epoch
+in sampling order through ``start_prefetch``/``get``, and the removed
+stage-parallel executor and augment-backend option are refused.
 """
+import dataclasses
 import time
 
 import numpy as np
 import pytest
 
-from repro.api import (AZURE_NC96, SenecaServer, TelemetryAggregator,
-                       resolve_augment_backend)
-from repro.data.pipeline import DSIPipeline, plan_stage_workers
+from repro.api import (AZURE_NC96, SenecaConfig, SenecaServer,
+                       SessionClosed, TelemetryAggregator,
+                       TelemetrySnapshot)
+from repro.data.augment import augment_batch_np
+from repro.data.pipeline import EXECUTORS, DSIPipeline
 from repro.data.storage import RemoteStorage
 from repro.data.synthetic import tiny
+from repro.kernels.augment.ops import augment_batch_seeded
 
-BACKENDS = ("numpy", "pallas")
+# the two batch augments, called alike: (images, crop_hw, seeds)
+AUGMENTERS = {
+    "augment_batch_np": augment_batch_np,
+    "augment_batch_seeded":
+        lambda imgs, crop_hw, seeds: augment_batch_seeded(imgs, seeds,
+                                                          *crop_hw),
+}
 
 
 def _server(ds, **kw):
@@ -113,151 +123,120 @@ def test_refill_errors_surface_in_stats():
 
 
 # ----------------------------------------------------------------------
-# batched augment backends: parity + per-sample seed determinism
-@pytest.mark.parametrize("backend", BACKENDS)
-def test_augment_backend_seed_determinism(backend):
+# batch augments: parity + per-sample seed determinism
+@pytest.mark.parametrize("augmenter", sorted(AUGMENTERS))
+def test_batch_augment_seed_determinism(augmenter):
     """Same seed -> same output row, independent of batch composition
-    (the stage executor's augment groups vary with cache hits)."""
-    be = resolve_augment_backend(backend)
+    (a route's augment groups vary with cache hits)."""
+    aug = AUGMENTERS[augmenter]
     rng = np.random.default_rng(0)
     imgs = rng.integers(0, 256, size=(7, 48, 40, 3), dtype=np.uint8)
     seeds = (np.arange(7) * 977 + 13).astype(np.int64)
-    full = be.augment_batch(imgs, (32, 24), seeds)
+    full = aug(imgs, (32, 24), seeds)
     assert full.shape == (7, 32, 24, 3) and full.dtype == np.float32
     for i in (0, 3, 6):                 # singleton batches (bucket B=1)
-        solo = be.augment_batch(imgs[i:i + 1], (32, 24), seeds[i:i + 1])
+        solo = aug(imgs[i:i + 1], (32, 24), seeds[i:i + 1])
         np.testing.assert_allclose(solo[0], full[i], atol=2e-6)
 
 
-def test_augment_backend_parity_numpy_vs_pallas():
+def test_batch_augment_parity_numpy_vs_pallas():
     rng = np.random.default_rng(7)
     imgs = rng.integers(0, 256, size=(6, 64, 64, 3), dtype=np.uint8)
     seeds = (np.arange(6) * 1_000_003 + 42).astype(np.int64)
-    out_np = resolve_augment_backend("numpy").augment_batch(
-        imgs, (56, 56), seeds)
-    out_pl = resolve_augment_backend("pallas").augment_batch(
-        imgs, (56, 56), seeds)
+    out_np = augment_batch_np(imgs, (56, 56), seeds)
+    out_pl = augment_batch_seeded(imgs, seeds, 56, 56)
     np.testing.assert_allclose(out_pl, out_np, atol=2e-6)
 
 
-def test_augment_backend_registry_errors():
-    with pytest.raises(ValueError, match="unknown augment backend"):
-        resolve_augment_backend("nope")
-    with pytest.raises(TypeError, match="AugmentBackend"):
-        resolve_augment_backend(object())
-    # "jax" is accepted as an alias for the Pallas kernel path
-    assert resolve_augment_backend("jax").name == "pallas"
-
-
 # ----------------------------------------------------------------------
-# stage-parallel executor semantics
-@pytest.mark.parametrize("augment_backend", BACKENDS)
-def test_stage_parallel_epoch_coverage_in_order(augment_backend):
+# both routes behind start_prefetch()/get()
+@pytest.mark.parametrize("executor", EXECUTORS)
+def test_prefetch_epoch_coverage_in_order(executor):
+    """Every id of the epoch exactly once, in the sampler's order."""
     ds = tiny(n=96)
-    server = _server(ds, use_ods=False)          # naive: exact epoch cover
+    ref = _server(ds, use_ods=False)             # naive: exact epoch cover
+    with ref.open_session(batch_size=12) as sess:
+        order = [int(s) for _ in range(96 // 12)
+                 for s in sess.next_batch_ids()[0]]
+    ref.close()
+    assert sorted(order) == list(range(96))
+    server = _server(ds, use_ods=False)
     pipe = DSIPipeline(server.open_session(batch_size=12), RemoteStorage(ds),
-                       n_workers=4, executor="stage-parallel",
-                       augment_backend=augment_backend)
+                       n_workers=4, executor=executor)
+    pipe.start_prefetch()
     seen = []
     for _ in range(96 // 12):
-        b = pipe.next_batch()
+        b = pipe.get(timeout=60.0)
         assert b["images"].shape == (12, *ds.crop_hw, 3)
         assert b["labels"].shape == (12,)
-        assert np.isfinite(b["images"]).all()
-        assert abs(float(b["images"].mean())) < 2.0
+        assert np.isfinite(np.asarray(b["images"])).all()
+        assert abs(float(np.asarray(b["images"]).mean())) < 2.0
         seen.extend(b["ids"].tolist())
-    assert sorted(seen) == list(range(96)), \
-        "stage-parallel executor dropped/duplicated samples"
+    assert seen == order, f"{executor} route dropped/duplicated/reordered"
     pipe.stop()
     server.close()
 
 
-def test_stage_parallel_matches_per_sample_content():
-    """Both executors produce identical tensors for a given sample id
-    (numpy augment backend: bit-identical; seeds are per-sample).
+def test_host_prefetch_get_matches_serial_next_batch():
+    """The host route's prefetched batches equal its serial ones, bitwise:
+    seeds are per sample, not per thread or batch.
 
     The augmented tier is disabled (encoded-only split): background
     refills admit entries under their own seed, and whether a sample is
     served from a refill is a thread race — with no augmented tier every
     sample is augmented fresh from its (epoch, sid) seed.
     """
-    def run(executor):
+    def run(prefetch):
         ds = tiny(n=48)
         server = _server(ds, use_ods=False, split=(1.0, 0.0, 0.0))
         pipe = DSIPipeline(server.open_session(batch_size=8),
-                           RemoteStorage(ds), n_workers=3,
-                           executor=executor)
+                           RemoteStorage(ds), n_workers=3)
+        if prefetch:
+            pipe.start_prefetch()
         out = {}
         for _ in range(48 // 8):
-            b = pipe.next_batch()
+            b = pipe.get(timeout=60.0) if prefetch else pipe.next_batch()
             for i, sid in enumerate(b["ids"].tolist()):
                 out[sid] = b["images"][i]
         pipe.stop()
         server.close()
         return out
 
-    a, b = run("per-sample"), run("stage-parallel")
+    a, b = run(False), run(True)
     assert a.keys() == b.keys()
     for sid in a:
         np.testing.assert_array_equal(a[sid], b[sid])
 
 
-def test_stage_parallel_reports_queue_gauges():
-    ds = tiny(n=64)
-    server = _server(ds)
-    pipe = DSIPipeline(server.open_session(batch_size=8), RemoteStorage(ds),
-                       n_workers=4, executor="stage-parallel")
-    for _ in range(4):
-        pipe.next_batch()
-    tel = server.stats()["telemetry"]
-    assert set(tel["queue_occupancy"]) == \
-        {"fetch", "decode", "augment", "collate", "out"}
-    assert all(0.0 <= v <= 1.0 for v in tel["queue_occupancy"].values())
-    assert "queue_depth" in tel
-    pipe.stop()
-    server.close()
-
-
-def test_stage_parallel_session_close_fails_fast():
+@pytest.mark.parametrize("executor", EXECUTORS)
+def test_session_close_fails_fast(executor):
     """Closing the session externally must surface as SessionClosed from
-    the consumer promptly (the per-sample executor's behavior), not as a
-    full get_batch timeout."""
-    from repro.api import SessionClosed
+    the next batch, on either route, not as a hang."""
     ds = tiny(n=64)
     server = _server(ds)
     sess = server.open_session(batch_size=8)
     pipe = DSIPipeline(sess, RemoteStorage(ds), n_workers=2,
-                       executor="stage-parallel", prefetch=1)
+                       executor=executor)
     pipe.next_batch()
     sess.close()
+    t0 = time.monotonic()
     with pytest.raises(SessionClosed):
-        # drain whatever was in flight, then the closed session surfaces
-        for _ in range(20):
-            pipe.next_batch()
+        pipe.next_batch()
+    assert time.monotonic() - t0 < 5.0
     pipe.stop()
     server.close()
 
 
-def test_stage_worker_counts_scale_calibration_rates():
-    """t_a/t_da conversion honors per-stage worker counts: a single
-    augment thread must not be scaled by the global concurrency."""
+def test_calibration_rates_scale_by_concurrency():
+    """t_a/t_da are node-aggregate rates: per-sample latencies scaled by
+    the registered worker concurrency."""
     tel = TelemetryAggregator()
     tel.add_concurrency(4)
-    for _ in range(4):
-        tel.record_stage("decode", 0.010, workers=2)
-        tel.record_stage("augment", 0.020, workers=1)
+    tel.record_stage("decode", 0.010)
+    tel.record_stage("augment", 0.020)
     snap = tel.snapshot()
-    assert snap.t_a == pytest.approx(1 / 0.020)          # 1 thread
-    # pipelined chain rate: min(2/0.010, 1/0.020) = 50
-    assert snap.t_da == pytest.approx(min(2 / 0.010, 1 / 0.020))
-    # without per-stage counts the seed semantics hold (conc-scaled)
-    tel2 = TelemetryAggregator()
-    tel2.add_concurrency(4)
-    tel2.record_stage("decode", 0.010)
-    tel2.record_stage("augment", 0.020)
-    snap2 = tel2.snapshot()
-    assert snap2.t_a == pytest.approx(4 / 0.020)
-    assert snap2.t_da == pytest.approx(4 / 0.030)
+    assert snap.t_a == pytest.approx(4 / 0.020)
+    assert snap.t_da == pytest.approx(4 / 0.030)
 
 
 def test_unknown_executor_rejected():
@@ -276,54 +255,68 @@ def test_unknown_executor_rejected():
     server.close()
 
 
-def test_executor_stop_clears_stage_worker_scaling():
-    """A stopped stage-parallel executor must not leave its group sizes
-    scaling latencies reported by later per-sample pipelines."""
-    ds = tiny(n=64)
-    server = _server(ds)
-    pipe = DSIPipeline(server.open_session(batch_size=8), RemoteStorage(ds),
-                       n_workers=4, executor="stage-parallel")
-    pipe.next_batch()
-    assert server.service.telemetry._stage_workers   # set while running
-    pipe.stop()
-    assert not server.service.telemetry._stage_workers
-    server.close()
-
-
 # ----------------------------------------------------------------------
-# telemetry-driven worker-group sizing
-def test_plan_stage_workers_splits_by_stage_ewmas():
+# the removed stage-parallel executor and augment-backend option
+def test_executors_are_the_two_routes():
+    assert EXECUTORS == ("per-sample", "device")
+
+
+def test_stage_parallel_executor_rejected():
+    ds = tiny(n=16)
+    server = _server(ds)
+    with pytest.raises(ValueError, match="unknown executor"):
+        DSIPipeline(server.open_session(batch_size=4), RemoteStorage(ds),
+                    executor="stage-parallel")
+    server.close()
+
+
+@pytest.mark.parametrize("where", ["pipeline", "server", "config"])
+def test_augment_backend_option_rejected(where):
+    ds = tiny(n=16)
+    if where == "config":
+        assert "augment_backend" not in {
+            f.name for f in dataclasses.fields(SenecaConfig)}
+        with pytest.raises(TypeError):
+            _server(ds, augment_backend="pallas")
+        return
+    server = _server(ds)
+    with pytest.raises(TypeError):
+        if where == "pipeline":
+            DSIPipeline(server.open_session(batch_size=4),
+                        RemoteStorage(ds), augment_backend="pallas")
+        else:
+            SenecaServer(server.service.cfg, augment_backend="pallas")
+    server.close()
+
+
+def test_telemetry_has_no_queue_or_worker_group_fields():
+    """The snapshot and stats() carry no queue gauges and no per-stage
+    worker groups; t_da is conc/(dec+aug) whatever the reporter."""
+    names = {f.name for f in dataclasses.fields(TelemetrySnapshot)}
+    assert not names & {"queue_depth", "queue_occupancy"}
     tel = TelemetryAggregator()
-    # no data: even split, fetch 2x-oversubscribed (IO-bound group)
-    assert plan_stage_workers(tel, 4) == (4, 2)
-    tel.record_stage("fetch_storage", 0.03)
-    tel.record_stage("decode", 0.01)
-    assert plan_stage_workers(tel, 4) == (6, 1)     # fetch-bound
-    tel2 = TelemetryAggregator()
-    tel2.record_stage("fetch_storage", 0.001)
-    tel2.record_stage("decode", 0.099)
-    assert plan_stage_workers(tel2, 6) == (2, 5)    # decode-bound, >=1
-    assert plan_stage_workers(tel2, 1) == (2, 1)    # budget floor of 2
+    assert not hasattr(tel, "record_queue")
+    with pytest.raises(TypeError):
+        tel.record_stage("decode", 0.010, workers=2)
+    tel.add_concurrency(3)
+    tel.record_stage("decode", 0.004)
+    tel.record_stage("augment", 0.002)
+    snap = tel.snapshot()
+    assert snap.t_da == pytest.approx(3 / (0.004 + 0.002))
+    assert not {"queue_depth", "queue_occupancy"} & set(tel.as_dict())
 
 
-def test_stage_parallel_elastic_groups_track_telemetry():
-    """The executor re-plans its fetch/decode groups from the stage EWMAs
-    every batch: targets track the plan (within the +-1 anti-churn
-    hysteresis plus the EWMA movement since the last batch)."""
-    ds = tiny(n=128)
+def test_stats_have_no_augment_backend_key():
+    ds = tiny(n=32)
     server = _server(ds)
     pipe = DSIPipeline(server.open_session(batch_size=8), RemoteStorage(ds),
-                       n_workers=4, executor="stage-parallel")
-    for _ in range(6):
-        pipe.next_batch()
-    counts = pipe._executor.worker_counts()
-    assert counts["fetch"] >= 1 and counts["decode"] >= 1
-    pipe.stop()                     # freeze telemetry before comparing
+                       n_workers=1)
+    pipe.next_batch()
+    stats = server.stats()
+    assert "augment_backend" not in stats
+    assert not {"queue_depth", "queue_occupancy"} & set(stats["telemetry"])
+    pipe.stop()
     server.close()
-    planned = plan_stage_workers(server.service.telemetry, 4)
-    target = pipe._executor._target
-    assert abs(target["fetch"] - planned[0]) <= 2
-    assert abs(target["decode"] - planned[1]) <= 2
 
 
 # ----------------------------------------------------------------------

@@ -173,10 +173,10 @@ def test_runner_validates_trace_and_construction():
     with pytest.raises(ValueError, match="unknown executor"):
         JobSpec("bad", executor="warp-speed")   # fails at spec time,
     #   not inside a job thread with a session already open
-    # virtual clock rejects the stage-parallel executor up front
+    # virtual clock runs only the per-sample reference route
     vrunner = WorkloadRunner(server, storage, clock=VirtualClock())
     with pytest.raises(ValueError, match="per-sample"):
-        vrunner.run([JobSpec("sp", executor="stage-parallel")])
+        vrunner.run([JobSpec("dev", executor="device")])
     server.close()
 
 
@@ -406,19 +406,20 @@ def test_pipeline_consume_hook_fires_per_batch():
     assert np.asarray(got).shape == (3, 8)
 
 
-def test_pipeline_consume_hook_fires_on_stage_parallel_get():
-    """The hook contract holds on the stage-parallel consumer path too:
-    get() fires it once per retrieved batch (review finding: it used to
-    bypass the hook entirely)."""
+@pytest.mark.parametrize("executor", ["per-sample", "device"])
+def test_pipeline_consume_hook_fires_on_get(executor):
+    """The hook contract holds on the prefetching consumer path too:
+    get() fires it once per retrieved batch, on either route, and the
+    prefetch thread never fires it."""
     from repro.data.pipeline import DSIPipeline
     ds = tiny(n=48)
     server = _server(ds)
     calls = []
     pipe = DSIPipeline(server.open_session(batch_size=8),
-                       RemoteStorage(ds), n_workers=2,
-                       executor="stage-parallel",
+                       RemoteStorage(ds), n_workers=2, executor=executor,
                        consume_hook=lambda b: calls.append(
                            b["ids"].tolist()))
+    pipe.start_prefetch()
     got = [pipe.get(timeout=60.0)["ids"].tolist() for _ in range(3)]
     assert calls == got
     pipe.stop()
