@@ -24,18 +24,28 @@ The ``"sigmoid"`` router (DeepSeek-V3's ``noaux_tc`` with one group) is a
 layer told which experts it holds: it scores all ``n_experts``, selects
 the top k by score plus a correction bias, and computes only the held
 experts' part of the result, for every assignment routed to them (no
-capacity, nothing dropped), as one grouped matmul over the assignments
-sorted by expert (``jax.lax.ragged_dot``).  The layer holds experts
-``[0, held)``; under expert parallelism each model-rank holds its slice
-of them and the parts are summed with the same ``psum``.  With
-``n_held`` below ``n_experts`` what the experts held elsewhere would add
-is left out.
+capacity, nothing dropped).  The assignments are sorted by held expert,
+the others last, and a loop runs over chunks of C sorted rows, as many
+as cover the held count (a traced trip count): each chunk gathers its
+rows by token, runs them through grouped matmuls
+(``jax.lax.ragged_dot``) and adds them, weighted, into a float32
+accumulator by token.  So the rows a layer moves follow its held
+count, not all T * k.  C is a multiple of 512 near a quarter of the
+rows routing spread evenly would give the held experts.  A custom VJP
+keeps only the inputs and the sort's index arrays, and runs the same
+loop backward, recomputing each chunk (a ``lax.cond`` a chunk under the
+layer ``scan`` would copy the expert weights into its residuals every
+chunk).  The layer holds experts ``[0, held)``; under expert
+parallelism each model-rank holds its slice of them and the parts are
+summed with the same ``psum``.  With ``n_held`` below ``n_experts``
+what the experts held elsewhere would add is left out.
 
-Every MoE layer returns, beside its output, ``{"loss", "held", "load"}``:
-its balance loss times ``aux_loss_weight``, the share of its
+Every MoE layer returns, beside its output, ``{"loss", "held", "load",
+"rows"}``: its balance loss times ``aux_loss_weight``, the share of its
 assignments that land on experts it holds (1 where it holds them all),
-and the busiest held expert's assignment count over the held experts'
-mean.
+the busiest held expert's assignment count over the held experts'
+mean, and the assignment rows its experts' part moved over all of them
+(at most 1; 1 for the capacity dispatch).
 """
 from __future__ import annotations
 
@@ -180,35 +190,121 @@ def _seq_balance_loss(scores: jax.Array, top_e: jax.Array,
     return jnp.mean(jnp.sum(f * p, axis=-1))
 
 
+def _chunk_rows(rows: int, n_held: int, n_experts: int) -> int:
+    """The sorted rows one trip of the held-expert loop moves: a multiple
+    of 512 near a quarter of the ``rows`` that routing spread evenly over
+    ``n_experts`` would give the ``n_held`` held ones, at most ``rows``."""
+    quarter = rows * n_held / n_experts / 4
+    return min(rows, 512 * max(1, round(quarter / 512)))
+
+
+def _trips(n_rows: jax.Array, C: int) -> jax.Array:
+    """Chunks of C sorted rows that cover the first ``n_rows``."""
+    return (n_rows + C - 1) // C
+
+
+def _chunk(order, starts, ends, k: int, C: int, c):
+    """Chunk c, the sorted rows [cC, cC + C): (their assignments, their
+    tokens, which of them are held, each held expert's rows among them)."""
+    lo = c * C
+    rows = jax.lax.dynamic_slice_in_dim(order, lo, C)
+    valid = (lo + jnp.arange(C) < ends[-1])[:, None]
+    sizes = jnp.clip(ends, lo, lo + C) - jnp.clip(starts, lo, lo + C)
+    return rows, rows // k, valid, sizes
+
+
+def _chunk_out(xs, ws, sizes, valid, we_gate, we_up, we_out):
+    """The held experts' gated MLPs on rows ``xs`` grouped by ``sizes``,
+    times the rows' routing weights ``ws``, in float32.  Rows not
+    ``valid`` give 0 on the way in and out: the chip leaves a grouped
+    matmul's rows outside every group unwritten."""
+    xs = jnp.where(valid, xs, 0)
+    g = jnp.where(valid, jax.lax.ragged_dot(xs, we_gate, sizes), 0)
+    u = jnp.where(valid, jax.lax.ragged_dot(xs, we_up, sizes), 0)
+    h = jax.nn.silu(g.astype(F32)).astype(xs.dtype) * u
+    y = jnp.where(valid, jax.lax.ragged_dot(h, we_out, sizes), 0)
+    return y.astype(F32) * ws[:, None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _chunked_experts(k, C, x2d, w_flat, we_gate, we_up, we_out, order,
+                     starts, ends):
+    """``_chunk_out`` summed into tokens, (T, D) in ``x2d``'s dtype: a
+    loop over the chunks that cover the held rows, accumulating in
+    float32.  The index arrays come in as arguments (closed over, they
+    would leak tracers under ``scan`` and ``checkpoint``)."""
+    def body(c, acc):
+        rows, tok, valid, sizes = _chunk(order, starts, ends, k, C, c)
+        return acc.at[tok].add(_chunk_out(x2d[tok], w_flat[rows], sizes,
+                                          valid, we_gate, we_up, we_out))
+
+    # the carry made from x2d carries its varying axes under shard_map
+    acc = jax.lax.fori_loop(0, _trips(ends[-1], C), body,
+                            jnp.zeros_like(x2d, F32))
+    return acc.astype(x2d.dtype)
+
+
+def _chunked_experts_fwd(k, C, *args):
+    return _chunked_experts(k, C, *args), args
+
+
+def _chunked_experts_bwd(k, C, res, dout):
+    """The same loop: each chunk's forward again, pulled back from its
+    rows of ``dout`` to its gathered rows and the experts, the rows'
+    cotangents added into float32 accumulators by token and assignment."""
+    x2d, w_flat, we_gate, we_up, we_out, order, starts, ends = res
+    experts = (we_gate, we_up, we_out)
+
+    def body(c, acc):
+        dx, dw, d_experts = acc
+        rows, tok, valid, sizes = _chunk(order, starts, ends, k, C, c)
+        _, pull = jax.vjp(
+            lambda xs, ws, *ex: _chunk_out(xs, ws, sizes, valid, *ex),
+            x2d[tok], w_flat[rows], *experts)
+        g_x, g_w, *g_ex = pull(dout[tok].astype(F32))
+        return (dx.at[tok].add(g_x.astype(F32)), dw.at[rows].add(g_w),
+                tuple(a + g.astype(F32) for a, g in zip(d_experts, g_ex)))
+
+    zeros = (jnp.zeros_like(x2d, F32), jnp.zeros_like(w_flat, F32),
+             tuple(jnp.zeros_like(w, F32) for w in experts))
+    dx, dw, d_experts = jax.lax.fori_loop(0, _trips(ends[-1], C), body,
+                                          zeros)
+    return (dx.astype(x2d.dtype), dw.astype(w_flat.dtype),
+            *(d.astype(w.dtype) for d, w in zip(d_experts, experts)),
+            None, None, None)
+
+
+_chunked_experts.defvjp(_chunked_experts_fwd, _chunked_experts_bwd)
+
+
 def _held_experts(x2d: jax.Array, top_e: jax.Array, top_w: jax.Array,
-                  e_start: int, we_gate, we_up, we_out):
+                  e_start: int, we_gate, we_up, we_out,
+                  chunk: Optional[int] = None):
     """The part of the MoE output that experts [e_start, e_start + n_held)
     give, for every assignment routed to them.
 
-    Assignments are sorted by held expert (the others last) and each
-    held expert's rows go through its gated MLP in one grouped matmul.
-    Rows past the held ones are masked on the way in and out: the chip
-    leaves a grouped matmul's rows outside every group unwritten.
+    Assignments are sorted by held expert (the others last).  A loop
+    runs over chunks of ``chunk`` sorted rows, as many as cover the held
+    ones: each gathers its rows by token, puts each held expert's rows
+    through its gated MLP in one grouped matmul, weights them by their
+    routing weights and adds them into a float32 (T, D) accumulator by
+    token.  So the rows moved follow the held count, not T * k.  Its
+    custom VJP keeps only the inputs and the index arrays, and runs the
+    same loop backward, recomputing each chunk.  ``chunk`` defaults to
+    ``_chunk_rows`` for a layer that holds every expert.
     Returns (out (T, D), each held expert's assignment count)."""
     T, D = x2d.shape
     k = top_e.shape[1]
     n_held = we_gate.shape[0]
+    C = chunk or _chunk_rows(T * k, n_held, n_held)
     local = top_e.reshape(-1) - e_start
-    held = (local >= 0) & (local < n_held)
-    key = jnp.where(held, local, n_held)
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
     order = jnp.argsort(key, stable=True)
+    order = jnp.pad(order, (0, -(T * k) % C))
     sizes = jnp.bincount(key, length=n_held + 1)[:n_held]
-    keep = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]
-    xs = jnp.where(keep, x2d[order // k], 0)
-    g = jnp.where(keep, jax.lax.ragged_dot(xs, we_gate, sizes), 0)
-    u = jnp.where(keep, jax.lax.ragged_dot(xs, we_up, sizes), 0)
-    h = jax.nn.silu(g.astype(F32)).astype(x2d.dtype) * u
-    y = jnp.where(keep, jax.lax.ragged_dot(h, we_out, sizes), 0)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(T * k, dtype=order.dtype))
-    w = jnp.where(held, top_w.reshape(-1), 0.0).astype(y.dtype)
-    out = jnp.einsum("tkd,tk->td", y[inverse].reshape(T, k, D),
-                     w.reshape(T, k))
+    ends = jnp.cumsum(sizes)
+    out = _chunked_experts(k, C, x2d, top_w.reshape(-1), we_gate, we_up,
+                           we_out, order, ends - sizes, ends)
     return out, sizes
 
 
@@ -229,13 +325,14 @@ def _capacity(T: int, k: int, E: int, factor: float) -> int:
 
 def _routed(x2d: jax.Array, B: int, route_p: Dict, experts: Tuple,
             e, e_start, ep_axis: Optional[str] = None
-            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+            ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """The routed experts' part of the output that ``experts`` (from
     expert ``e_start`` on) give for the B sequences in ``x2d``, the
-    unweighted balance loss, and each held expert's assignment count.
-    Inside ``shard_map``, ``ep_axis`` names the axis the experts are
-    split over."""
+    unweighted balance loss, each held expert's assignment count, and
+    the assignment rows the experts' part moved.  Inside ``shard_map``,
+    ``ep_axis`` names the axis the experts are split over."""
     T = x2d.shape[0]
+    rows = T * e.top_k
     with jax.named_scope("moe.route"):
         if e.router == "sigmoid":
             top_e, top_w, scores = _route_sigmoid(
@@ -252,13 +349,17 @@ def _routed(x2d: jax.Array, B: int, route_p: Dict, experts: Tuple,
         x2d, top_w = jax.lax.pcast((x2d, top_w), ep_axis, to="varying")
     with jax.named_scope("moe.experts"):
         if e.router == "sigmoid":
-            y, _ = _held_experts(x2d, top_e, top_w, e_start, *experts)
+            C = _chunk_rows(rows, experts[0].shape[0], e.n_experts)
+            y, sizes = _held_experts(x2d, top_e, top_w, e_start, *experts,
+                                     chunk=C)
+            moved = jnp.minimum(_trips(jnp.sum(sizes), C) * C, rows)
         else:
             cap = _capacity(T, e.top_k, e.n_experts, e.capacity_factor)
             y = _dispatch_local(x2d, top_e, top_w, e_start,
                                 experts[0].shape[0], cap, *experts)
+            moved = jnp.asarray(rows)
     counts = jnp.bincount(top_e.reshape(-1), length=e.n_experts)[:e.held]
-    return y, bal, counts
+    return y, bal, counts, moved
 
 
 # ---------------------------------------------------------------------------
@@ -267,7 +368,8 @@ def _routed(x2d: jax.Array, B: int, route_p: Dict, experts: Tuple,
 
 def moe_ffn(p: Dict, x: jax.Array, cfg: ModelConfig
             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """MoE FFN. x: (B, S, D). Returns (y, {"loss", "held", "load"})."""
+    """MoE FFN. x: (B, S, D). Returns (y, {"loss", "held", "load",
+    "rows"})."""
     e = cfg.moe
     B, S, D = x.shape
     rules = current_rules()
@@ -298,26 +400,37 @@ def moe_ffn(p: Dict, x: jax.Array, cfg: ModelConfig
 
         def body(x_l, route_p, experts):
             Bl, Sl, Dl = x_l.shape
-            part, bal, counts = _routed(
+            if reduce_axes:
+                # varying over the batch axes like the rows they meet,
+                # the experts' cotangents are summed over those axes
+                # once, here, and not in each trip of the held-expert
+                # loop, whose trip count is the rank's own
+                experts = jax.lax.pcast(experts, reduce_axes,
+                                        to="varying")
+            part, bal, counts, moved = _routed(
                 x_l.reshape(Bl * Sl, Dl), Bl, route_p, experts, e,
                 jax.lax.axis_index(ep_axis) * n_local, ep_axis)
             out = jax.lax.psum(part, ep_axis)
+            # every rank's rows over the whole batch's assignments
+            moved = jax.lax.psum(moved, (ep_axis, *reduce_axes))
             if reduce_axes:
                 bal = jax.lax.pmean(bal, reduce_axes)
                 counts = jax.lax.psum(counts, reduce_axes)
-            return out.reshape(Bl, Sl, Dl), bal, counts
+            return out.reshape(Bl, Sl, Dl), bal, counts, moved
 
-        y, bal, counts = jax.shard_map(
+        y, bal, counts, moved = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(batch_spec, None, None), P(), P(ep_axis, None, None)),
-            out_specs=(P(batch_spec, None, None), P(), P()),
+            out_specs=(P(batch_spec, None, None), P(), P(), P()),
         )(x, route_p, experts)
     else:
-        y, bal, counts = _routed(x.reshape(B * S, D), B, route_p, experts,
-                                 e, 0)
+        y, bal, counts, moved = _routed(x.reshape(B * S, D), B, route_p,
+                                        experts, e, 0)
         y = y.reshape(B, S, D)
     held, load = _load_stats(counts, e.top_k, B * S, e.n_experts)
+    rows = jnp.minimum(moved.astype(F32) / (B * S * e.top_k), 1.0)
 
     y = y + shared_y
     return (shard(y, "batch", "act_seq", "act_embed"),
-            {"loss": bal * e.aux_loss_weight, "held": held, "load": load})
+            {"loss": bal * e.aux_loss_weight, "held": held, "load": load,
+             "rows": rows})

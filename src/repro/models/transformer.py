@@ -146,9 +146,10 @@ def _scan_blocks(blocks, x, body, remat: str, aux=None):
 
 def _aux_zero(stack: Dict):
     """The sum the decoder carries over ``stack``'s layers: each MoE
-    layer's ``{"loss", "held", "load"}``, else a zero loss."""
+    layer's ``{"loss", "held", "load", "rows"}``, else a zero loss."""
     if "moe" in stack:
-        return {k: jnp.zeros((), F32) for k in ("loss", "held", "load")}
+        return {k: jnp.zeros((), F32)
+                for k in ("loss", "held", "load", "rows")}
     return jnp.zeros((), F32)
 
 
@@ -289,9 +290,12 @@ def loss_and_stats(params, cfg: ModelConfig, batch: Dict, *,
                    remat: str = "none") -> Tuple[jax.Array, Dict]:
     """(loss, step counters).  MoE configs count, as means over their MoE
     layers, ``moe_held_share`` (the share of the assignments that land
-    on the experts a layer holds) and ``moe_load_max_over_mean`` (the
-    busiest held expert's assignments over the held experts' mean);
-    other configs count nothing."""
+    on the experts a layer holds), ``moe_load_max_over_mean`` (the
+    busiest held expert's assignments over the held experts' mean) and
+    ``moe_rows_share`` (the assignment rows the experts' part of a layer
+    moved, over all of them: the held share rounded up to whole chunks
+    of the held-expert loop, 1 for a capacity dispatch); other configs
+    count nothing."""
     logits, aux = forward(params, cfg, batch, remat=remat)
     if cfg.family == "encoder":
         lbl = batch["labels"]
@@ -302,7 +306,8 @@ def loss_and_stats(params, cfg: ModelConfig, batch: Dict, *,
         return ce + aux, {}
     n_moe = cfg.n_layers - cfg.first_dense_layers
     return ce + aux["loss"], {"moe_held_share": aux["held"] / n_moe,
-                              "moe_load_max_over_mean": aux["load"] / n_moe}
+                              "moe_load_max_over_mean": aux["load"] / n_moe,
+                              "moe_rows_share": aux["rows"] / n_moe}
 
 
 # ---------------------------------------------------------------------------

@@ -187,13 +187,18 @@ def test_moe_ep_matches_local_dispatch():
     """)
 
 
-@pytest.mark.parametrize("arch, n_held", [
-    ("kimi-vl-a3b", 0), ("kimi-vl-a3b", 4), ("deepseek-moe-16b", 0)])
-def test_moe_ep_train_matches_one_device(arch, n_held):
+@pytest.mark.parametrize("arch, n_held, seq", [
+    ("kimi-vl-a3b", 0, 32), ("kimi-vl-a3b", 4, 32),
+    ("deepseek-moe-16b", 0, 32), ("kimi-vl-a3b", 0, 512)],
+    ids=["kimi-vl-a3b-0", "kimi-vl-a3b-4", "deepseek-moe-16b-0",
+         "kimi-vl-a3b-0-chunks"])
+def test_moe_ep_train_matches_one_device(arch, n_held, seq):
     """A MoE arch laid out as the program lays it out (experts over
     'model'): loss, step counters and every gradient equal one device's.
     The softmax router runs drop-free and without its balance loss,
-    whose mean over batch shards is not the whole batch's."""
+    whose mean over batch shards is not the whole batch's.  At 512
+    tokens a sequence each rank's held-expert loop takes several chunks
+    (its carries vary over 'model' like its inputs)."""
     out = run_sub(f"""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
@@ -213,7 +218,13 @@ def test_moe_ep_train_matches_one_device(arch, n_held):
         m = build(cfg)
         params = m.init(jax.random.key(0), dtype=jnp.float32)
         batch = make_batch(jax.random.key(1), m, TRAIN_4K,
-                           reduced_shape=(4, 32))
+                           reduced_shape=(4, {seq}))
+        if {seq} > 32:
+            # a rank's 2 sequences: twice a chunk's rows, evenly routed,
+            # on its 4 of 16 experts
+            from repro.models.moe import _chunk_rows
+            rows = 2 * {seq} * cfg.moe.top_k
+            assert rows * 4 // 16 >= 2 * _chunk_rows(rows, 4, 16)
 
         def run(p, b):
             (loss, stats), g = jax.value_and_grad(

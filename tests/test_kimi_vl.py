@@ -218,6 +218,108 @@ def test_held_experts_ignore_rows_past_the_groups(monkeypatch):
                                    rtol=1e-4, atol=1e-5)
 
 
+def _routing_with_held(n, T, k, n_experts, n_held, seed):
+    """top_e (T, k), k distinct experts a token, exactly n of the T * k
+    assignments on experts [0, n_held); top_w (T, k) positive."""
+    rng = np.random.default_rng(seed)
+    held = np.zeros(T * k, bool)
+    held[rng.choice(T * k, n, replace=False)] = True
+    held = held.reshape(T, k)
+    top_e = np.empty((T, k), np.int32)
+    for t in range(T):
+        top_e[t, held[t]] = rng.permutation(n_held)[:held[t].sum()]
+        top_e[t, ~held[t]] = n_held + rng.permutation(
+            n_experts - n_held)[:(~held[t]).sum()]
+    top_w = rng.uniform(0.1, 1.0, (T, k)).astype(np.float32)
+    return jnp.asarray(top_e), jnp.asarray(top_w)
+
+
+def _dense_layer(x, top_e, top_w, wg, wu, wo):
+    """The held experts [0, G) on every token, weighted by their gates."""
+    gate = jnp.stack([jnp.sum(jnp.where(top_e == j, top_w, 0), 1)
+                      for j in range(wg.shape[0])], 1)
+    h = jax.nn.silu(jnp.einsum("td,jdf->tjf", x, wg)) \
+        * jnp.einsum("td,jdf->tjf", x, wu)
+    return jnp.einsum("tjf,jfd,tj->td", h, wo, gate)
+
+
+# 4 held of 16 experts, T * k = 48 * 4 = 192 rows in chunks of 16
+CHUNK = 16
+
+
+@pytest.mark.parametrize("n", [0, CHUNK - 5, CHUNK, 2 * CHUNK + 1, 192],
+                         ids=["none", "below-a-chunk", "one-chunk",
+                              "chunks-and-one", "every-row"])
+def test_chunked_held_experts_match_the_dense_layer(monkeypatch, n):
+    """The loop over chunks of sorted rows, at every kind of held count
+    and with rows past the groups left unwritten: the output and the
+    gradients for the rows, the three expert weights and the routing
+    weights are the dense computation's."""
+    _unwritten_past_the_groups(monkeypatch)
+    cfg = small(n_experts=16, top_k=4, n_held=4)
+    p = f32_init(moe_mod.moe_defs(cfg), 5)
+    T = 48
+    x = jax.random.normal(jax.random.key(6), (T, cfg.d_model), jnp.float32)
+    top_e, top_w = _routing_with_held(n, T, 4, 16, 4, seed=n)
+
+    def program(x, top_w, wg, wu, wo):
+        return moe_mod._held_experts(x, top_e, top_w, 0, wg, wu, wo,
+                                     chunk=CHUNK)
+
+    def dense(x, top_w, wg, wu, wo):
+        return _dense_layer(x, top_e, top_w, wg, wu, wo)
+
+    args = (x, top_w, p["we_gate"], p["we_up"], p["we_out"])
+    with jax.default_matmul_precision("highest"):
+        got, sizes = jax.jit(program)(*args)
+        assert int(np.sum(sizes)) == n
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dense(*args)),
+                                   rtol=1e-4, atol=1e-5)
+        grads = jax.jit(jax.grad(
+            lambda *a: jnp.sum(program(*a)[0] ** 2),
+            argnums=(0, 1, 2, 3, 4)))(*args)
+        want = jax.grad(lambda *a: jnp.sum(dense(*a) ** 2),
+                        argnums=(0, 1, 2, 3, 4))(*args)
+    for g, w in zip(grads, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)
+
+
+def test_chunked_held_experts_train_under_scan_and_checkpoint(monkeypatch):
+    """Layers scanned under ``checkpoint``, as the step runs them, with
+    each layer's routing a traced input: every gradient is the dense
+    layers'."""
+    _unwritten_past_the_groups(monkeypatch)
+    cfg = small(n_experts=16, top_k=4, n_held=4)
+    T, L = 48, 3
+    stack = jax.tree.map(lambda *a: jnp.stack(a), *[
+        f32_init(moe_mod.moe_defs(cfg), 20 + i) for i in range(L)])
+    routes = [_routing_with_held(n, T, 4, 16, 4, seed=n)
+              for n in (CHUNK + 3, 0, 150)]
+    top_e = jnp.stack([r[0] for r in routes])
+    top_w = jnp.stack([r[1] for r in routes])
+    x = jax.random.normal(jax.random.key(13), (T, cfg.d_model), jnp.float32)
+
+    def run(layer):
+        def loss(x, top_w, wg, wu, wo):
+            def body(h, xs):
+                te, tw, g, u, o = xs
+                return h + layer(h, te, tw, g, u, o), None
+            h, _ = jax.lax.scan(jax.checkpoint(body), x,
+                                (top_e, top_w, wg, wu, wo))
+            return jnp.sum(h ** 2)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(
+            x, top_w, stack["we_gate"], stack["we_up"], stack["we_out"])
+
+    with jax.default_matmul_precision("highest"):
+        got = run(lambda h, te, tw, g, u, o: moe_mod._held_experts(
+            h, te, tw, 0, g, u, o, chunk=CHUNK)[0])
+        want = run(_dense_layer)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)
+
+
 def test_expert_shares_sum_to_the_uncut_layer():
     """Eight chips each hold 2 of 16 experts: their parts of the layer,
     with the shared experts counted once, add up to the uncut layer."""
@@ -334,6 +436,39 @@ def test_step_counts_routing_for_moe_configs_only(arch, counted):
         held = float(metrics["moe_held_share"])
         assert (0.0 < held < 1.0) if arch == ARCH else held == 1.0
         assert float(metrics["moe_load_max_over_mean"]) >= 1.0
+
+
+@pytest.mark.parametrize("arch, n_held", [
+    (ARCH, 4), (ARCH, 0), ("deepseek-moe-16b", 0), ("vit-huge", 0),
+    ("qwen3-8b", 0)])
+def test_step_counts_the_rows_moved(arch, n_held):
+    """``moe_rows_share``: absent without MoE, 1 where a layer holds
+    every expert, else the held share rounded up to whole chunks."""
+    cfg = registry.get_reduced(arch)
+    if cfg.moe is not None and cfg.moe.router == "sigmoid":
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+            cfg.moe, n_held=n_held))
+    m = build(cfg)
+    params = m.init(jax.random.key(0))
+    B, S = 4, 256
+    batch = make_batch(jax.random.key(1), m, TRAIN_4K, reduced_shape=(B, S))
+    metrics = jax.jit(m.loss_and_stats)(params, batch)[1]
+    if cfg.moe is None:
+        assert "moe_rows_share" not in metrics
+        return
+    rows = float(metrics["moe_rows_share"])
+    held = float(metrics["moe_held_share"])
+    e = cfg.moe
+    if e.held == e.n_experts:
+        assert rows == 1.0 and held == 1.0
+        return
+    C = moe_mod._chunk_rows(B * S * e.top_k, e.held, e.n_experts)
+    assert C < B * S * e.top_k * held       # more than one chunk a layer
+    assert held <= rows < held + C / (B * S * e.top_k) + 1e-6, (held, rows)
+    # whole chunks: the layers' trips add up to a whole number
+    trips = rows * (cfg.n_layers - cfg.first_dense_layers) \
+        * B * S * e.top_k / C
+    assert abs(trips - round(trips)) < 1e-3, trips
 
 
 def test_step_carries_the_named_scopes():
